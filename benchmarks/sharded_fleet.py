@@ -59,7 +59,6 @@ def run_worker(devices: int, shards: int, vehicles: int, tasks: int,
              if not f.startswith("--xla_force_host_platform_device_count")]
     flags.append(f"--xla_force_host_platform_device_count={devices}")
     env["XLA_FLAGS"] = " ".join(flags)
-    env.setdefault("JAX_PLATFORMS", "cpu")
     with tempfile.NamedTemporaryFile("r", suffix=".json") as out:
         cmd = [sys.executable, "-m", "benchmarks.sharded_fleet", "--worker",
                "--out", out.name, "--devices-forced", str(devices),

@@ -34,6 +34,7 @@ the remaining rounds bit-identically to an uninterrupted run:
 import argparse
 
 from repro.config import CheckpointSpec, EnergyAllocConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.sim import scenarios
 from repro.sim.simulator import IoVSimulator, SimConfig
 
@@ -69,6 +70,7 @@ def main():
                          "--checkpoint-dir, then finish the remaining "
                          "rounds (bit-identical to an uninterrupted run)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.list_scenarios:
         for name in scenarios.list_scenarios():

@@ -16,6 +16,7 @@ import jax.numpy as jnp
 from repro.config import LoRAConfig
 from repro.core import aggregation as agg
 from repro.core.lora import tree_rank
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer as T
 from repro.optim import adam, apply_updates
 
@@ -45,6 +46,7 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-0.5b")
     args = ap.parse_args()
+    enable_compile_cache()
     mod = importlib.import_module(
         "repro.configs." + args.arch.replace("-", "_").replace(".", "_"))
     cfg = mod.reduced()

@@ -22,6 +22,7 @@ import numpy as np
 
 from repro.config import CheckpointSpec, LoRAConfig, ServeSpec
 from repro.launch.adapter_cache import AdapterStore
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.serve import ServeEngine
 from repro.models import transformer as T
 from repro.sim.simulator import IoVSimulator, SimConfig
@@ -35,6 +36,7 @@ def main():
     ap.add_argument("--block-size", type=int, default=0,
                     help="KV block size (> 0 pages the caches; 0 = dense)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     with tempfile.TemporaryDirectory() as ckpt_dir:
         # -- train a small fleet, checkpointing every round -------------

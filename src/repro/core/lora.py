@@ -70,7 +70,7 @@ def apply_lora_linear(base: Dict[str, jnp.ndarray], adapter: Optional[Adapter],
             return lora_matmul(
                 x, base["w"], adapter["a"], adapter["b"],
                 scale=scale, rank_mask=rank_mask,
-                interpret=runmode.lora_kernel_interpret(),
+                interpret=runmode.kernel_interpret(),
                 use_kernel=not runmode.lora_kernel_oracle())
     y = x @ base["w"]
     if "b" in base:

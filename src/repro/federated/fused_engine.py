@@ -130,6 +130,21 @@ def fleet_slots(num_vehicles: int, num_shards: int,
     return (v % num_shards) * per_shard + v // num_shards, vp
 
 
+def adapter_deviation(got, ref, start) -> Tuple[float, float]:
+    """How far adapter tree ``got`` lies from the reference ``ref``, both
+    trained from ``start``: (max |got − ref| over every entry,
+    ||got − ref|| / ||ref − start||). The second is the deviation as a
+    share of the reference's own update, so it separates rounding noise
+    (a small share) from a fault in what was trained — a wrong batch, a
+    dropped half-batch, wrong labels — which changes the update itself
+    (a share of order one). 0.0 when the reference did not move."""
+    gl, rl, sl = (jax.tree_util.tree_leaves(t) for t in (got, ref, start))
+    d_max = max(float(jnp.max(jnp.abs(g - r))) for g, r in zip(gl, rl))
+    num = sum(float(jnp.sum(jnp.square(g - r))) for g, r in zip(gl, rl))
+    den = sum(float(jnp.sum(jnp.square(r - s0))) for r, s0 in zip(rl, sl))
+    return d_max, (float(np.sqrt(num / den)) if den > 0.0 else 0.0)
+
+
 class FusedRoundEngine:
     """One-jit-program-per-round engine bound to an :class:`IoVSimulator`.
 
@@ -209,6 +224,14 @@ class FusedRoundEngine:
         self.part_trivial = self.part.trivial
         self.Rmax = cfg.lora.max_rank
         self.steps = cfg.local_steps
+        # Per-layer remat of the local-training loss on accelerators: the
+        # fleet vmap multiplies every saved activation by the vehicle
+        # count, and at ViT-Base width 24 vehicles save ~11 GB of
+        # residuals, which with the round's state does not fit one 16 GB
+        # chip (recomputing each layer keeps ~3.7 GB). Host memory does
+        # not bind on the CPU, where the saved-residual program is the one
+        # the bit-exact kernel/oracle parity tests pin.
+        self.remat = jax.default_backend() != "cpu"
         self.opt = adam(cfg.lr)
         self.lora_max = dataclasses.replace(cfg.lora, rank=self.Rmax)
         self.S0 = cfg.lora.scale          # server-side merge/redistribute α/r₀
@@ -284,15 +307,20 @@ class FusedRoundEngine:
         self._zero_fleet = jax.tree_util.tree_map(
             lambda x: jnp.zeros((self.Vp,) + x.shape, x.dtype), tmpl)
         # per-task RSU partials: merged-delta tree with a leading (K,) axis
-        self._zero_partials = jax.tree_util.tree_map(
-            lambda x: jnp.zeros((self.K,) + x.shape, x.dtype),
-            self._zero_merged)
+        # (non-trivial tiers only)
+        self._zero_partials = None if self.tier_trivial else (
+            jax.tree_util.tree_map(
+                lambda x: jnp.zeros((self.K,) + x.shape, x.dtype),
+                self._zero_merged))
         # per-lane buffered merged deltas (semi-sync participation): the
         # same merged-delta tree with a leading (Vp,) fleet axis, so the
-        # buffer shards over the fleet mesh like every per-vehicle array
-        self._zero_buf = jax.tree_util.tree_map(
-            lambda x: jnp.zeros((self.Vp,) + x.shape, x.dtype),
-            self._zero_merged)
+        # buffer shards over the fleet mesh like every per-vehicle array.
+        # Built only for the policy that reads it: at ViT-Base width it is
+        # Vp full d_in×d_out deltas per layer (8 GB for 24 vehicles).
+        self._zero_buf = None if self.part_trivial else (
+            jax.tree_util.tree_map(
+                lambda x: jnp.zeros((self.Vp,) + x.shape, x.dtype),
+                self._zero_merged))
         if self.mesh is not None:
             # the fleet template lives sharded on the mesh, so everything
             # scattered into it (fresh staging) inherits the placement;
@@ -312,6 +340,7 @@ class FusedRoundEngine:
         self._jit_round = jax.jit(self._round_step)
         self._jit_scan: Dict[int, Any] = {}
         self.check_dev = 0.0
+        self.check_rel = 0.0
 
     # ------------------------------------------------------------------
     @staticmethod
@@ -603,7 +632,7 @@ class FusedRoundEngine:
 
                 def loss(p):
                     return T.loss_fn(params, p, cfg, lora_max, batch,
-                                     scale=scale)
+                                     scale=scale, remat=self.remat)
 
                 (_, metrics), grads = jax.value_and_grad(
                     loss, has_aux=True)(a)
@@ -624,12 +653,57 @@ class FusedRoundEngine:
 
         return jax.vmap(one)(adapters, scales, tokens, labels, counts)
 
+    def _per_shard(self, fn, fleet_args: Sequence[int]):
+        """``fn`` (a whole-fleet function) run on each device's own lanes.
+        On a fleet mesh the local training and eval are lane-parallel by
+        construction, so they run under ``shard_map``: arguments at
+        ``fleet_args`` split along their leading (Vp,) axis, the rest
+        (frozen params, the shared eval batch) replicate, and each device
+        traces the vmap over its Vp / n_shards lanes. With no fleet
+        argument (the global eval) every device computes the same
+        replicated result. The Pallas kernels inside then compile per
+        device; XLA cannot partition a Mosaic call on its own. Identity on
+        the trivial topology."""
+        if self.mesh is None:
+            return fn
+        lanes = P(self.shard_spec.axis_name)
+        out = lanes if fleet_args else P()
+
+        def per_shard(*args):
+            specs = tuple(lanes if i in fleet_args else P()
+                          for i in range(len(args)))
+            return jax.shard_map(fn, mesh=self.mesh, in_specs=specs,
+                                 out_specs=out, check_vma=False)(*args)
+        return per_shard
+
     def _eval_fleet(self, params, adapters, scales, batch):
         def ev(ad, scale):
             _, m = T.loss_fn(params, ad, self.model_cfg, self.lora_max,
                              batch, scale=scale)
             return m["accuracy"]
         return jax.vmap(ev)(adapters, scales)
+
+    def _global_eval(self, params, merged, batch, round_idx):
+        """Accuracy of a task's merged adapter on its held-out set, through
+        the seed-0 SVD at max rank (the serial engine's eval_adapters
+        view)."""
+        # seed 0. On a fleet mesh it is computed from the traced round
+        # index instead of written as a literal: a literal key makes every
+        # layer's SVD sketch a compile-time constant, and several of those
+        # in one program crash JAX 0.9's Shardy partitioner on a
+        # multi-device mesh (SIGSEGV in ConstantOrScalarSplitterPass).
+        # min(round, 0) is 0 in every round; the unsharded program keeps
+        # the literal.
+        seed = 0 if self.mesh is None else jnp.minimum(round_idx, 0)
+        gad = agg.factors_full(agg.merged_svd(merged, self.Rmax, seed=seed),
+                               self.S0)
+
+        def accuracy(params, gad, batch):
+            _, met = T.loss_fn(params, gad, self.model_cfg, self.lora_max,
+                               batch, scale=self.alpha / self.Rmax)
+            return met["accuracy"]
+
+        return self._per_shard(accuracy, fleet_args=())(params, gad, batch)
 
     def _round_step(self, carry, x, data):
         cfg = self.cfg
@@ -694,11 +768,12 @@ class FusedRoundEngine:
             dist = self._constrain(dist)
 
             # 3. fleet megastep: local fine-tuning + held-out local eval
-            new_ads = self._constrain(self._train_fleet(
+            new_ads = self._constrain(self._per_shard(
+                self._train_fleet, fleet_args=(1, 2, 3, 4, 5))(
                 params, dist, scale_arg, x["tokens"][ti], x["labels"][ti],
                 x["counts"][ti]))
-            local_acc = self._eval_fleet(params, new_ads, scale_arg,
-                                         self.local_eval[ti])
+            local_acc = self._per_shard(self._eval_fleet, fleet_args=(1, 2))(
+                params, new_ads, scale_arg, self.local_eval[ti])
 
             # 4. §III-C four-stage costs over the staged channel
             costs = cm.vehicle_round_costs_vec(
@@ -899,15 +974,10 @@ class FusedRoundEngine:
                 rec["released"].append(jnp.sum(release).astype(jnp.int32))
                 rec["rel_weight"].append(jnp.sum(relw).astype(jnp.float32))
 
-            # 7. global eval on the task's held-out set (seed-0 SVD at
-            #    max_rank — the serial engine's eval_adapters view)
+            # 7. global eval on the task's held-out set
             def do_eval(m):
-                gad = agg.factors_full(
-                    agg.merged_svd(m, self.Rmax, seed=0), self.S0)
-                _, met = T.loss_fn(params, gad, self.model_cfg,
-                                   self.lora_max, self.eval_batches[ti],
-                                   scale=self.alpha / self.Rmax)
-                return met["accuracy"]
+                return self._global_eval(params, m, self.eval_batches[ti],
+                                         round_idx)
 
             # serial evals only when this round kept uploads AND a global
             # model exists — for non-trivial tiers the global only appears
@@ -1243,10 +1313,12 @@ class FusedRoundEngine:
     # ------------------------------------------------------------------
     def _run_check(self, x, check) -> None:
         """fused_check: replay the serial LocalTrainer on the identical
-        staged batches and distributed adapters; record the max adapter
-        deviation (the batched_check machinery, extended to fused)."""
+        staged batches and distributed adapters; record the deviation (the
+        batched_check machinery, extended to fused): the max adapter-entry
+        difference and, per vehicle, the relative one of
+        :func:`adapter_deviation`."""
         sim = self.sim
-        dev = 0.0
+        dev = rel = 0.0
         for ti in range(self.T):
             ids = np.where(x["active"][ti])[0]
             if not len(ids):
@@ -1266,8 +1338,10 @@ class FusedRoundEngine:
                 got = lora_lib.truncate_adapter_tree(
                     jax.tree_util.tree_map(lambda a: a[v],
                                            check["new"][ti]), r)
-                for ga, rb in zip(jax.tree_util.tree_leaves(got),
-                                  jax.tree_util.tree_leaves(ref_ad)):
-                    dev = max(dev, float(jnp.max(jnp.abs(ga - rb))))
+                d_max, d_rel = adapter_deviation(got, ref_ad, ref_in)
+                dev = max(dev, d_max)
+                rel = max(rel, d_rel)
         self.check_dev = max(self.check_dev, dev)
+        self.check_rel = max(self.check_rel, rel)
         self.sim.engine_check_dev = max(self.sim.engine_check_dev, dev)
+        self.sim.engine_check_rel = max(self.sim.engine_check_rel, rel)

@@ -106,6 +106,6 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     """
     if interpret is None:
         from repro.models import runmode
-        interpret = runmode.lora_kernel_interpret()
+        interpret = runmode.kernel_interpret()
     return _fa_jit(q, k, v, causal, sliding_window, sm_scale, block_q,
                    block_k, bool(interpret))

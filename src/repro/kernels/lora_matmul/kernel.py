@@ -9,11 +9,14 @@ trip. t = x·A is O(M·K·r), r ≤ 64 ≪ N, computed once by the wrapper (its
 cost is ~r/N of the base GEMM).
 
 Two operands beyond the GEMM inputs:
-  scale — shape (1,) f32 in SMEM, read as a scalar in the epilogue. Traced,
-          not baked into the kernel: the fused round engine threads
-          *per-vehicle dynamic* scales (alpha/rank), so a static scale
-          would recompile per distinct value and break the one-compile
-          round-body contract.
+  scale — shape (1, 1) f32 in SMEM, read as a scalar in the epilogue.
+          Traced, not baked into the kernel: the fused round engine
+          threads *per-vehicle dynamic* scales (alpha/rank), so a static
+          scale would recompile per distinct value and break the
+          one-compile round-body contract. Two-dimensional because the
+          fleet vmap batches it: a (1,) operand becomes a (V, 1) array
+          with a (1,) block, which the TPU lowering rejects, while a
+          (1, 1) block always equals the array's last two dims.
   mask  — shape (1, r) f32 rank mask (rank_arange_mask row). The epilogue
           computes (t⊙mask)·B, extending the rank-padding invariant into
           the kernel: a rank-r vehicle under max_rank padding produces
@@ -31,11 +34,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-# jax 0.4.x ships TPUCompilerParams; newer releases renamed it to
-# CompilerParams — accept either so the kernels track both APIs
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
 
 
 def _lora_mm_kernel(x_ref, w_ref, t_ref, b_ref, m_ref, s_ref, o_ref,
@@ -58,7 +56,8 @@ def _lora_mm_kernel(x_ref, w_ref, t_ref, b_ref, m_ref, s_ref, o_ref,
         adapter = jax.lax.dot_general(
             t, bb, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        o_ref[...] = (acc_scr[...] + s_ref[0] * adapter).astype(o_ref.dtype)
+        o_ref[...] = (acc_scr[...] + s_ref[0, 0] * adapter).astype(
+            o_ref.dtype)
 
 
 def lora_matmul_kernel(x: jnp.ndarray, w: jnp.ndarray, t: jnp.ndarray,
@@ -67,7 +66,7 @@ def lora_matmul_kernel(x: jnp.ndarray, w: jnp.ndarray, t: jnp.ndarray,
                        block_m: int = 128, block_n: int = 128,
                        block_k: int = 512,
                        interpret: bool = False) -> jnp.ndarray:
-    """x:(M,K) w:(K,N) t=(x·A):(M,r) b:(r,N) mask:(1,r) scale:(1,) → (M,N)."""
+    """x:(M,K) w:(K,N) t=x·A:(M,r) b:(r,N) mask:(1,r) scale:(1,1) → (M,N)."""
     M, K = x.shape
     N = w.shape[1]
     r = t.shape[1]
@@ -90,7 +89,7 @@ def lora_matmul_kernel(x: jnp.ndarray, w: jnp.ndarray, t: jnp.ndarray,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, w, t, b, mask, scale)

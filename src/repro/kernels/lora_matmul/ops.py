@@ -60,7 +60,7 @@ def _lora_mm(x, w, a, b, scale, mask, cfg):
     wp = jnp.pad(w, ((0, pk), (0, pn)))
     tp = jnp.pad(tf, ((0, pm), (0, 0)))
     bp = jnp.pad(b, ((0, 0), (0, pn)))
-    s1 = jnp.asarray(scale, jnp.float32).reshape((1,))
+    s1 = jnp.asarray(scale, jnp.float32).reshape((1, 1))
     m2 = jnp.asarray(mask, jnp.float32).reshape((1, r))
     out = lora_matmul_kernel(xp, wp, tp, bp, m2, s1, block_m=bm,
                              block_n=bn, block_k=bk, interpret=interpret)

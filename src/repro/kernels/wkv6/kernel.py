@@ -20,11 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax 0.4.x ships TPUCompilerParams; newer releases renamed it to
-# CompilerParams — accept either so the kernels track both APIs
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
 
 def _wkv6_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, y_ref, sfin_ref,
                  state_scr, *, q: int, nc: int):
@@ -103,7 +98,7 @@ def wkv6_kernel(r: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             jax.ShapeDtypeStruct((B, H, K, K), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((K, K), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(r, k, v, logw, u.reshape(H, 1, K))

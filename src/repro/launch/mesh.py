@@ -12,12 +12,24 @@ from __future__ import annotations
 from typing import Tuple
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``Auto``. JAX 0.9 defaults new
+    meshes to ``Explicit`` axes, under which an eager op on a mesh-sharded
+    array (the fleet engine's fresh-draw scatter, ``server.fresh_padded``)
+    must run inside ``jax.set_mesh``; the round program places its own
+    arrays with ``NamedSharding`` and ``with_sharding_constraint``, which
+    is the ``Auto`` contract."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_fleet_mesh(num_shards: int = 0, *, axis_name: str = "fleet"):
@@ -36,7 +48,7 @@ def make_fleet_mesh(num_shards: int = 0, *, axis_name: str = "fleet"):
             f"fleet mesh wants {n} devices but only "
             f"{jax.local_device_count()} are visible (CI forces host "
             "devices via XLA_FLAGS=--xla_force_host_platform_device_count)")
-    return jax.make_mesh((n,), (axis_name,))
+    return _auto_mesh((n,), (axis_name,))
 
 
 def data_axes(mesh) -> Tuple[str, ...]:

@@ -28,7 +28,8 @@ incrementally instead of max-seq upfront, and a retired tenant's blocks
 recycle to new admissions — still through the one compiled decode body
 (tables are fixed-shape int32 data, never statics).
 
-CLI example (batched requests on CPU with the reduced config):
+CLI example (batched requests through ServeEngine; reduced config unless
+--full):
     PYTHONPATH=src python -m repro.launch.serve --arch qwen2-0.5b --tokens 32
 """
 from __future__ import annotations
@@ -118,6 +119,15 @@ def make_decode_step(cfg: ModelConfig, lora: LoRAConfig, mesh, *,
 # ---------------------------------------------------------------------------
 # Multi-tenant serving engine
 # ---------------------------------------------------------------------------
+
+def _snapshot(host: np.ndarray) -> jnp.ndarray:
+    """Ship host state the engine keeps mutating in place (positions,
+    scales, block tables). On the CPU backend ``jnp.asarray`` may alias
+    the numpy buffer, and the decode call is dispatched asynchronously, so
+    an in-place update after the call could race the program's read of
+    it; a private copy makes the call see exactly this step's state."""
+    return jnp.asarray(host.copy())
+
 
 class ServeEngine:
     """Batched multi-tenant decode over rank-padded adapter slots.
@@ -377,14 +387,14 @@ BlockAllocator`, lanes grow block-by-block as their streams lengthen, and
         if self.paged:
             self._ensure_blocks()
             logits, self._caches, self._pools = self._decode(
-                self.params, self._adapters, jnp.asarray(self._scales),
+                self.params, self._adapters, _snapshot(self._scales),
                 toks, self._caches, self._pools,
-                jnp.asarray(self.allocator.tables),
-                jnp.asarray(self._positions))
+                _snapshot(self.allocator.tables),
+                _snapshot(self._positions))
         else:
             logits, self._caches = self._decode(
-                self.params, self._adapters, jnp.asarray(self._scales),
-                toks, self._caches, jnp.asarray(self._positions))
+                self.params, self._adapters, _snapshot(self._scales),
+                toks, self._caches, _snapshot(self._positions))
         self._positions += 1
         return logits
 
@@ -416,7 +426,7 @@ BlockAllocator`, lanes grow block-by-block as their streams lengthen, and
 
 
 # ---------------------------------------------------------------------------
-# CPU demo CLI: batched request serving with the reduced config
+# CLI: batched request serving through ServeEngine
 # ---------------------------------------------------------------------------
 
 def main():
@@ -424,54 +434,39 @@ def main():
     import importlib
     import time
 
+    from repro.config import get_arch
+    from repro.launch.compile_cache import enable_compile_cache
+
     parser = argparse.ArgumentParser()
     parser.add_argument("--arch", default="qwen2-0.5b")
+    parser.add_argument("--full", action="store_true",
+                        help="serve the full (not reduced) config")
     parser.add_argument("--batch", type=int, default=4)
     parser.add_argument("--prompt-len", type=int, default=16)
     parser.add_argument("--tokens", type=int, default=32)
     args = parser.parse_args()
+    enable_compile_cache()
 
-    mod = importlib.import_module(
-        "repro.configs." + args.arch.replace("-", "_").replace(".", "_"))
-    cfg = mod.reduced()
-    lora = LoRAConfig(rank=4)
-    key = jax.random.PRNGKey(0)
-    params = T.init_params(key, cfg, dtype=jnp.float32)
-
-    B = args.batch
-    clen = args.prompt_len + args.tokens
-    caches = T.init_caches(cfg, B, clen, dtype=jnp.float32)
+    if args.full:
+        cfg = get_arch(args.arch)
+    else:
+        cfg = importlib.import_module(
+            "repro.configs." + args.arch.replace("-", "_").replace(".", "_")
+        ).reduced()
+    params = T.init_params(jax.random.PRNGKey(0), cfg, dtype=jnp.float32)
+    spec = ServeSpec(max_batch=args.batch,
+                     cache_len=args.prompt_len + args.tokens)
+    # unassigned lanes decode the base model (zero adapter, zero scale)
+    engine = ServeEngine(params, cfg, LoRAConfig(rank=4), spec)
     rng = np.random.default_rng(0)
-    prompt = rng.integers(0, cfg.vocab_size, (B, args.prompt_len))
+    prompts = rng.integers(0, cfg.vocab_size, (args.batch, args.prompt_len))
 
-    traces = [0]
-
-    def _decode_body(params, adapters, tok, caches, pos):
-        traces[0] += 1          # runs only on (re)trace
-        return T.decode_step(params, adapters, cfg, lora, tok, caches,
-                             pos)
-
-    decode = jax.jit(_decode_body)
-
-    # prefill via repeated decode (simple reference path on CPU), then
-    # greedy generation — every token through the SAME jitted step
     t0 = time.time()
-    tok = jnp.asarray(prompt[:, :1], jnp.int32)
-    outs = []
-    for pos in range(clen - 1):
-        logits, caches = decode(params, None, tok, caches,
-                                jnp.asarray(pos, jnp.int32))
-        if pos + 1 < args.prompt_len:
-            tok = jnp.asarray(prompt[:, pos + 1:pos + 2], jnp.int32)
-        else:
-            tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            outs.append(np.asarray(tok)[:, 0])
-    jax.block_until_ready(caches)
+    gen = engine.generate(prompts, args.tokens)
     dt = time.time() - t0
-    gen = np.stack(outs, 1)
-    compiles = traces[0]
-    print(f"served {B} requests × {gen.shape[1]} tokens in {dt:.1f}s "
-          f"({B * gen.shape[1] / dt:.1f} tok/s, "
+    compiles = engine.compile_count
+    print(f"served {args.batch} requests × {gen.shape[1]} tokens in "
+          f"{dt:.1f}s ({args.batch * gen.shape[1] / dt:.1f} tok/s, "
           f"{compiles} decode compile{'s' if compiles != 1 else ''})")
     print("sample:", gen[0][:16])
 

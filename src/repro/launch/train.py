@@ -28,6 +28,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.config import LoRAConfig, ModelConfig
 from repro.launch import sharding as sh
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer as T
 from repro.optim import adam, apply_updates
 
@@ -146,9 +147,11 @@ def main():
     parser.add_argument("--rank", type=int, default=8)
     parser.add_argument("--lr", type=float, default=1e-3)
     parser.add_argument("--full", action="store_true",
-                        help="use the full (not reduced) config")
+                        help="use the full (not reduced) config; with "
+                             "--simulate, train ViT-Base at its published "
+                             "widths")
     parser.add_argument("--pallas", choices=("off", "auto", "on"),
-                        default="off",
+                        default="auto",
                         help="kernelized LoRA GEMM + flash attention "
                              "dispatch: off = jnp paths, auto = compiled "
                              "kernels iff running on TPU, on = force "
@@ -183,11 +186,20 @@ def main():
     if (args.checkpoint_every > 0 or args.resume) and not args.checkpoint_dir:
         parser.error("--checkpoint-every/--resume need --checkpoint-dir")
 
+    enable_compile_cache()
+    from repro.models import runmode
+    mode = {"off": False, "auto": "auto", "on": True}[args.pallas]
+    runmode.set_pallas_lora(mode)
+    runmode.set_pallas_attn(mode)
+
     if args.simulate:
         from repro.sim import scenarios
         kw: Dict[str, Any] = {"participation": args.participation}
         if args.engine:
             kw["engine"] = args.engine
+        if args.full:
+            from repro.configs.vit_base_paper import vit_base_paper
+            kw["train_arch"] = vit_base_paper()
         sim = scenarios.build_sim(args.simulate, rounds=args.rounds,
                                   seed=args.seed, **kw)
         R = sim.cfg.rounds
@@ -198,13 +210,6 @@ def main():
         print(f"done: {args.simulate} ({args.participation}), "
               f"{R} rounds, final acc={hist[-1]['accuracy']:.4f}")
         return
-
-    if args.pallas != "off":
-        from repro.models import runmode
-        v = True if args.pallas == "on" else "auto"
-        runmode.set_pallas_lora(v, interpret=runmode.lora_kernel_interpret())
-        runmode.set_pallas_attn(runmode.lora_kernel_enabled(),
-                                interpret=runmode.lora_kernel_interpret())
 
     if args.full:
         from repro.config import get_arch

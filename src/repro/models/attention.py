@@ -174,14 +174,15 @@ def attention_path(*, causal: bool, Sq: int, Sk: int, cap: float = 0.0,
     half of the dispatch table in DESIGN.md §6.
 
     'decode'       — Sq==1 causal with FAST_DECODE: direct cache attention
-    'pallas_flash' — USE_PALLAS_ATTN and the aligned causal train case
+    'pallas_flash' — flash kernel enabled (runmode.attn_kernel_enabled)
+                     and the aligned causal train case
     'direct'       — short sequences under DIRECT_ATTN_MAX_SEQ
     'jnp_flash'    — blocked online-softmax jnp fallback
     """
     from repro.models import runmode
     if Sq == 1 and causal and runmode.FAST_DECODE:
         return "decode"
-    if (runmode.USE_PALLAS_ATTN and causal and Sq == Sk and cap == 0.0
+    if (runmode.attn_kernel_enabled() and causal and Sq == Sk and cap == 0.0
             and (hd_k is None or hd_k == hd_v)):
         return "pallas_flash"
     if Sq > 1 and max(Sq, Sk) <= runmode.DIRECT_ATTN_MAX_SEQ:
@@ -215,7 +216,7 @@ def attend(q, k, v, *, causal: bool, q_positions, kv_positions=None,
         return flash_attention(q, k, v, causal=True,
                                sliding_window=sliding_window,
                                sm_scale=sm_scale,
-                               interpret=runmode.PALLAS_INTERPRET)
+                               interpret=runmode.kernel_interpret())
     if path == "direct":
         # short sequences: materializing the (Sq,Sk) scores is cheap, and
         # the blocked online-softmax machinery below (scan + per-block

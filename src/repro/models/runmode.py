@@ -8,34 +8,27 @@ the true op totals. Used ONLY by the dry-run's cost-extrapolation compiles
 """
 COST_UNROLL = False
 
-# USE_PALLAS_ATTN: route full-sequence attention through the Pallas flash
-# kernel (repro.kernels.flash_attention). On CPU this runs interpret mode
-# (slow — for validation); on TPU it is the production path. The jnp flash
-# ref stays the default so dry-run lowering works on the CPU backend.
-USE_PALLAS_ATTN = False
-PALLAS_INTERPRET = True     # CPU container: interpret mode
-
-
-def set_pallas_attn(v: bool, interpret: bool = True) -> None:
-    global USE_PALLAS_ATTN, PALLAS_INTERPRET
-    USE_PALLAS_ATTN = bool(v)
-    PALLAS_INTERPRET = bool(interpret)
-
-
-# USE_PALLAS_LORA: route every LoRA-targeted linear through the fused
-# base+adapter Pallas GEMM (repro.kernels.lora_matmul) — one output write,
-# no second HBM read of the activations (DESIGN.md §6). States:
-#   False    — pure-jnp path everywhere (default; bit-stable baseline)
-#   True     — kernelized path (interpret per PALLAS_INTERPRET off-TPU)
-#   "auto"   — backend autodetect: compiled kernel on TPU hosts, jnp
-#              elsewhere (the interpret-mode kernel is a validation tool,
-#              not a CPU fast path)
-#   "oracle" — same dispatch and custom_vjp as True but the forward is the
-#              jnp expression: the bit-exactness reference for the kernel
-# The fused round engine reads this at trace time (like USE_PALLAS_ATTN):
-# set it BEFORE the first round runs; later flips do not retrace an
-# already-compiled round program.
-USE_PALLAS_LORA = False
+# Pallas kernel dispatch (DESIGN.md §6). USE_PALLAS_ATTN routes aligned
+# causal full-sequence attention through the flash kernel
+# (repro.kernels.flash_attention); USE_PALLAS_LORA routes every unbiased
+# LoRA-targeted linear through the fused base+adapter GEMM
+# (repro.kernels.lora_matmul) — one output write, no second HBM read of
+# the activations. States:
+#   "auto"   — default: the compiled kernels on TPU hosts, the jnp paths
+#              everywhere else (so CPU runs trace the jnp programs)
+#   False    — jnp paths everywhere
+#   True     — kernels everywhere; off-TPU that is the Pallas interpreter
+#              (per PALLAS_INTERPRET), a validation tool that must be
+#              asked for explicitly
+#   "oracle" — (LoRA only) same dispatch and custom_vjp as True but the
+#              forward is the jnp expression: the kernel's bit-exactness
+#              reference
+# The round engines and the serve step read these at TRACE time: set them
+# before the first round runs; later flips do not retrace an
+# already-compiled program.
+USE_PALLAS_ATTN = "auto"
+USE_PALLAS_LORA = "auto"
+PALLAS_INTERPRET = True     # off-TPU kernels run in the interpreter
 
 
 def kernel_backend() -> str:
@@ -44,9 +37,19 @@ def kernel_backend() -> str:
     return jax.default_backend()
 
 
+def set_pallas_attn(v, interpret: bool = True) -> None:
+    """Flash-attention dispatch. v: False | True | "auto"."""
+    global USE_PALLAS_ATTN, PALLAS_INTERPRET
+    if v not in (False, True, "auto"):
+        raise ValueError(f"USE_PALLAS_ATTN must be False/True/'auto', "
+                         f"got {v!r}")
+    USE_PALLAS_ATTN = v
+    if v:
+        PALLAS_INTERPRET = bool(interpret)
+
+
 def set_pallas_lora(v, interpret: bool = True) -> None:
-    """Enable the kernelized LoRA linear.
-    v: False | True | "auto" | "oracle"."""
+    """LoRA-GEMM dispatch. v: False | True | "auto" | "oracle"."""
     global USE_PALLAS_LORA, PALLAS_INTERPRET
     if v not in (False, True, "auto", "oracle"):
         raise ValueError(f"USE_PALLAS_LORA must be False/True/'auto'/"
@@ -56,19 +59,27 @@ def set_pallas_lora(v, interpret: bool = True) -> None:
         PALLAS_INTERPRET = bool(interpret)
 
 
-def lora_kernel_enabled() -> bool:
-    if USE_PALLAS_LORA == "auto":
+def _enabled(v) -> bool:
+    if v == "auto":
         return kernel_backend() == "tpu"
-    return bool(USE_PALLAS_LORA)
+    return bool(v)
+
+
+def attn_kernel_enabled() -> bool:
+    return _enabled(USE_PALLAS_ATTN)
+
+
+def lora_kernel_enabled() -> bool:
+    return _enabled(USE_PALLAS_LORA)
 
 
 def lora_kernel_oracle() -> bool:
     return USE_PALLAS_LORA == "oracle"
 
 
-def lora_kernel_interpret() -> bool:
-    """TPU hosts always run the compiled kernel; everywhere else the
-    kernelized path is only available through the Pallas interpreter."""
+def kernel_interpret() -> bool:
+    """TPU hosts always run the compiled kernels; everywhere else a kernel
+    is only available through the Pallas interpreter."""
     if kernel_backend() == "tpu":
         return False
     return bool(PALLAS_INTERPRET)

@@ -141,6 +141,9 @@ class IoVSimulator:
         self.batched_trainer = BatchedLocalTrainer(
             self.model_cfg, cfg.lora, lr=cfg.lr, max_steps=cfg.local_steps)
         self.engine_check_dev = 0.0   # batched_check: max |batched − serial|
+        # fused_check: max over vehicles of ||fused − serial|| / ||serial
+        # − start|| (fused_engine.adapter_deviation)
+        self.engine_check_rel = 0.0
 
         # --- cost model (full-dimension backbone) ---
         self.cost_cfg = get_arch(cfg.cost_arch_id)

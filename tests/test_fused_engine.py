@@ -228,6 +228,23 @@ def test_fused_check_mode_deviation_bounded():
     assert sim.engine_check_dev < 1e-5
 
 
+def test_remat_trains_the_same_adapters():
+    """FusedRoundEngine.remat — on by default off the CPU, where the
+    fleet's saved activations would not fit one chip — recomputes each
+    layer's forward in the backward pass. It must train the same
+    adapters as the saved-residual program the CPU runs by default."""
+    saved = _sim("fused", rounds=1)
+    remat = _sim("fused", rounds=1)
+    assert not saved.fused.remat          # the CPU default
+    remat.fused.remat = True              # read at trace time
+    _assert_histories_match(saved.run(), remat.run(), rel=1e-6)
+    for ti in range(2):
+        ma, mb = saved.servers[ti].merged, remat.servers[ti].merged
+        assert (ma is None) == (mb is None)
+        if ma is not None:
+            assert _max_dev(ma, mb) < 1e-6, ti
+
+
 @pytest.mark.slow
 def test_fused_round_compiles_exactly_once():
     """Recompile guard (jax.log_compiles): across rounds with varying

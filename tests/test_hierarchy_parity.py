@@ -16,109 +16,17 @@ accuracy and budgets — plus the engine's serial-replay deviation
 
 Fast tier: two representative cells (kept small — the CI fast tier has a
 2-minute budget). Full grid (every preset × both rules × tier on/off):
-@slow.
+@slow, the merged rule here and hetlora in test_hierarchy_parity_hetlora.py
+(cells shared through tests/hierarchy_cells.py).
 """
 import logging
 
 import jax
-import numpy as np
 import pytest
 
-from repro.config import LoRAConfig, RSUTierSpec
+from hierarchy_cells import (TIER_OFF, TIER_ON, _assert_parity, _run_cell,
+                             _sim)
 from repro.sim import scenarios
-
-LORA = LoRAConfig(rank=4, max_rank=8, candidate_ranks=(2, 4, 8))
-
-# a non-trivial override tier for presets that ship without one: 2 RSUs
-# per task, partials synced every 2 rounds, nonzero migration penalty so
-# handoff accounting is exercised, not just association
-TIER_ON = RSUTierSpec(num_rsus_per_task=2, sync_period=2,
-                      staleness_decay=0.7, handoff_energy=5.0,
-                      handoff_latency=0.3)
-TIER_OFF = RSUTierSpec()
-
-
-def _tiny_cfg():
-    from repro.configs import vit_base_paper
-    return vit_base_paper.vit_base_paper().with_overrides(
-        name="vit-test-par", num_layers=2, d_model=32, num_heads=2,
-        num_kv_heads=2, head_dim=16, d_ff=64, vocab_size=64)
-
-
-def _sim(name, engine, method, tier, rounds, seed=1):
-    from repro.sim.simulator import IoVSimulator
-    cfg = scenarios.build_config(name, method=method, rounds=rounds,
-                                 seed=seed, engine=engine,
-                                 train_arch=_tiny_cfg(), lora=LORA,
-                                 local_steps=1, rsu_tier=tier)
-    return IoVSimulator(cfg)
-
-
-def _assert_parity(hs, he, rel=1e-4):
-    """Serial history hs vs engine history he."""
-    assert len(hs) == len(he)
-    for r_s, r_e in zip(hs, he):
-        for t_s, t_e in zip(r_s["tasks"], r_e["tasks"]):
-            assert t_s["active"] == t_e["active"]
-            assert t_s["departing"] == t_e["departing"]
-            assert t_s["handoffs"] == t_e["handoffs"]
-            assert t_s["comm_params"] == t_e["comm_params"]
-            assert t_s["mean_rank"] == pytest.approx(t_e["mean_rank"],
-                                                     abs=1e-5)
-            assert t_s["energy"] == pytest.approx(t_e["energy"], rel=rel)
-            assert t_s["lambda"] == pytest.approx(t_e["lambda"], abs=1e-4)
-        assert r_s["energy"] == pytest.approx(r_e["energy"], rel=rel)
-        # accuracy is quantized by the eval-set size: one borderline argmax
-        # flip under float-noise adapters moves it by ~1/N ≈ 3.5e-3 on the
-        # tiny test arch, so compare at one-flip granularity
-        assert r_s["accuracy"] == pytest.approx(r_e["accuracy"], abs=8e-3)
-        assert r_s["budgets"] == pytest.approx(r_e["budgets"], rel=1e-5)
-
-
-def _tree_norm(tree):
-    import jax.numpy as jnp
-    return float(jnp.sqrt(sum(jnp.sum(jnp.square(x))
-                              for x in jax.tree_util.tree_leaves(tree))))
-
-
-def _run_cell(name, method, tier, rounds=2, seed=1):
-    check_engine = "fused_check" if method == "ours" else "batched_check"
-    s = _sim(name, "serial", method, tier, rounds, seed)
-    e = _sim(name, check_engine, method, tier, rounds, seed)
-    hs, he = s.run(), e.run()
-    _assert_parity(hs, he)
-    # the *_check replay of the serial trainer on identical staged batches
-    # must sit at numerical noise. Single-round precision is pinned at
-    # 1e-5 by tests/test_fused_engine.py / test_batched_engine.py; across
-    # this sweep's multi-round cells the vmap-vs-serial GEMM reassociation
-    # noise is amplified by Adam's 1/√v normalization (worst observed
-    # ~3e-4 on highway-corridor). A REAL divergence — wrong batch, wrong
-    # adapter, wrong step count, wrong scale — lands at the update scale,
-    # orders of magnitude above this bound.
-    assert e.engine_check_dev < 1e-3, (name, method)
-    # aggregated server state: presence must agree engine-to-engine, and
-    # the states must sit at the same scale. Elementwise closeness is NOT
-    # asserted here: over 3 rounds the seeded randomized SVD rotates
-    # near-degenerate singular directions under 1e-5 perturbations, so
-    # engines drift in state while every trajectory metric still replays
-    # (the calibrated elementwise bound lives in
-    # test_fused_engine.py::test_sim_regression_fused_matches_serial).
-    for srv_s, srv_e in zip(s.servers, e.servers):
-        st_s = (srv_s.merged if method == "ours"
-                else srv_s.global_adapters)
-        st_e = (srv_e.merged if method == "ours"
-                else srv_e.global_adapters)
-        assert (st_s is None) == (st_e is None)
-        if st_s is not None:
-            na, nb = _tree_norm(st_s), _tree_norm(st_e)
-            assert np.isfinite(na) and np.isfinite(nb)
-            assert abs(na - nb) <= 0.5 * max(na, nb, 1e-6)
-        if not tier.trivial:
-            assert np.allclose(srv_s.partial_w, srv_e.partial_w,
-                               rtol=1e-4)
-            assert np.array_equal(srv_s.partial_age, srv_e.partial_age)
-    return hs
-
 
 # ---------------------------------------------------------------------------
 # Fast subset
@@ -135,12 +43,13 @@ def test_parity_urban_grid_hetlora_tier_fast():
 
 
 # ---------------------------------------------------------------------------
-# Full grid (slow): every preset × {merged, hetlora} × tier on/off
+# Full grid (slow): every preset × merged rule × tier on/off (the hetlora
+# half lives in test_hierarchy_parity_hetlora.py)
 # ---------------------------------------------------------------------------
 
 @pytest.mark.slow
 @pytest.mark.parametrize("name", scenarios.list_scenarios())
-@pytest.mark.parametrize("method", ["ours", "hetlora"])
+@pytest.mark.parametrize("method", ["ours"])
 @pytest.mark.parametrize("tier", [TIER_OFF, TIER_ON],
                          ids=["tier-off", "tier-on"])
 def test_parity_grid(name, method, tier):

@@ -69,11 +69,11 @@ def _assert_servers_bitexact(sa, sb):
 # ---------------------------------------------------------------------------
 
 def test_overrides_sets_and_restores():
-    assert runmode.USE_PALLAS_LORA is False
+    assert runmode.USE_PALLAS_LORA == "auto"
     with runmode.overrides(USE_PALLAS_LORA=True, DIRECT_ATTN_MAX_SEQ=0):
         assert runmode.USE_PALLAS_LORA is True
         assert runmode.DIRECT_ATTN_MAX_SEQ == 0
-    assert runmode.USE_PALLAS_LORA is False
+    assert runmode.USE_PALLAS_LORA == "auto"
     assert runmode.DIRECT_ATTN_MAX_SEQ == 64
 
 
@@ -82,7 +82,7 @@ def test_overrides_restores_on_exception():
         with runmode.overrides(USE_PALLAS_ATTN=True):
             assert runmode.USE_PALLAS_ATTN is True
             raise RuntimeError("boom")
-    assert runmode.USE_PALLAS_ATTN is False
+    assert runmode.USE_PALLAS_ATTN == "auto"
 
 
 def test_overrides_rejects_unknown_and_lowercase_keys():
@@ -97,11 +97,18 @@ def test_overrides_rejects_unknown_and_lowercase_keys():
 def test_set_pallas_lora_validates():
     with pytest.raises(ValueError, match="False/True/'auto'"):
         runmode.set_pallas_lora("yes")
-    assert runmode.USE_PALLAS_LORA is False
-    # 'auto' resolves by backend: off-TPU it must stay on the jnp path
-    with runmode.overrides(USE_PALLAS_LORA="auto"):
-        assert runmode.lora_kernel_enabled() == (
-            runmode.kernel_backend() == "tpu")
+    assert runmode.USE_PALLAS_LORA == "auto"
+    with pytest.raises(ValueError, match="False/True/'auto'"):
+        runmode.set_pallas_attn("oracle")
+    assert runmode.USE_PALLAS_ATTN == "auto"
+    # the 'auto' default resolves by backend: off-TPU both kernels stay
+    # on the jnp path and the interpreter is never chosen implicitly
+    on_tpu = runmode.kernel_backend() == "tpu"
+    assert runmode.lora_kernel_enabled() == on_tpu
+    assert runmode.attn_kernel_enabled() == on_tpu
+    from repro.models.attention import attention_path
+    assert attention_path(causal=True, Sq=24, Sk=24) == (
+        "pallas_flash" if on_tpu else "direct")
 
 
 # ---------------------------------------------------------------------------

@@ -68,10 +68,27 @@ def test_overrides_flow_through():
 # runs this file once per engine)
 # ---------------------------------------------------------------------------
 
+@pytest.fixture(scope="module")
+def one_round():
+    """One round of a preset, built and run once per (preset, resolved
+    engine) for the whole module: the default-engine test and the
+    explicit-engine test of the same engine share the simulator instead
+    of compiling and running it twice."""
+    runs = {}
+
+    def get(name, engine=None):
+        cfg = _build(name, engine=engine, rounds=2)
+        key = (name, IoVSimulator._resolve_engine(cfg))
+        if key not in runs:
+            sim = IoVSimulator(cfg)
+            runs[key] = (sim, sim.run(1))
+        return runs[key]
+    return get
+
+
 @pytest.mark.parametrize("name", scenarios.list_scenarios())
-def test_preset_one_round_default_engine(name):
-    sim = IoVSimulator(_build(name, rounds=2))
-    h = sim.run(1)
+def test_preset_one_round_default_engine(name, one_round):
+    sim, h = one_round(name)
     assert len(h) == 1
     r = h[0]
     assert np.isfinite(r["energy"]) and r["energy"] >= 0.0
@@ -110,9 +127,8 @@ def test_rush_hour_participation_varies_and_respects_presence():
 @pytest.mark.slow
 @pytest.mark.parametrize("name", scenarios.list_scenarios())
 @pytest.mark.parametrize("engine", ["batched", "fused"])
-def test_preset_one_round_each_engine(name, engine):
-    sim = IoVSimulator(_build(name, engine=engine, rounds=2))
-    h = sim.run(1)
+def test_preset_one_round_each_engine(name, engine, one_round):
+    _, h = one_round(name, engine)
     assert len(h) == 1
     assert np.isfinite(h[0]["energy"])
 

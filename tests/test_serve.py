@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import AxisType
 
 from conftest import reduced_config
 from repro.config import LoRAConfig, ServeSpec
@@ -34,7 +35,11 @@ PARITY_ARCHS = ["qwen2-0.5b", "zamba2-2.7b"]   # pure-attn + hybrid SSM
 
 
 def _mesh():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    # Auto axes, like repro.launch.mesh: the step factories place their
+    # operands with with_sharding_constraint, which JAX 0.9's default
+    # Explicit axes refuse
+    return jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def _nontrivial_adapters(cfg, lora, rank, seed=7):

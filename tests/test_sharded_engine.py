@@ -185,6 +185,37 @@ def test_fused_sharded_refuses_single_device_host():
             train_arch=_tiny_cfg(), lora=LORA))
 
 
+def test_fleet_mesh_takes_eager_fresh_scatter():
+    """make_fleet_mesh builds Auto axes, so the server's EAGER fresh-draw
+    scatter into a mesh-sharded fleet template (RSUServer.fresh_padded,
+    fused-engine staging) works outside any jax.set_mesh context. With
+    JAX 0.9's default Explicit axes that scatter raises even on a
+    one-device mesh, so this guards the axis-type choice on every host."""
+    from jax.sharding import AxisType
+
+    from repro.federated.server import RSUServer
+    from repro.launch import sharding as sh
+    from repro.launch.mesh import make_fleet_mesh
+    mesh = make_fleet_mesh(1)
+    assert mesh.axis_types == (AxisType.Auto,)
+    cfg = _tiny_cfg()
+    tmpl = T.init_adapters(jax.random.PRNGKey(0), cfg, LORA,
+                           rank=LORA.max_rank)
+    fleet = jax.tree_util.tree_map(
+        lambda x: jnp.zeros((4,) + x.shape, x.dtype), tmpl)
+    fleet = jax.device_put(fleet, sh.fleet_shardings(mesh, fleet,
+                                                     fleet_size=4))
+    srv = RSUServer(cfg, LORA, "ours", seed=0)
+    out = srv.fresh_padded(2, fleet=fleet, slots=[1, 3])
+    ref = RSUServer(cfg, LORA, "ours", seed=0).fresh_padded(2)
+    for got, want in zip(jax.tree_util.tree_leaves(out),
+                         jax.tree_util.tree_leaves(ref)):
+        assert got.sharding.spec[0] == "fleet"
+        np.testing.assert_array_equal(np.asarray(got[1::2]),
+                                      np.asarray(want))
+        assert not np.asarray(got[0::2]).any()
+
+
 # ---------------------------------------------------------------------------
 # Multi-device: engine parity (merged rule) + primitives (hetlora rule)
 # ---------------------------------------------------------------------------
