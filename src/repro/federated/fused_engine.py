@@ -88,6 +88,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro import telemetry
 from repro.core import aggregation as agg
 from repro.core import cost_model as cm
 from repro.core import energy_alloc
@@ -163,6 +164,11 @@ class FusedRoundEngine:
         self.sim = sim
         self.cfg = cfg
         self.check = bool(check)
+        # host spans and counters, shared with the simulator; the round
+        # index (len(sim.history) when the round began) tags each round's
+        # spans
+        self.telemetry = sim.telemetry
+        self._round_no = 0
         self.spec = sim.spec
         self.model_cfg = sim.model_cfg
         self.lora = cfg.lora
@@ -381,23 +387,24 @@ class FusedRoundEngine:
         vehicle-lane dimension is present shards it, the rest replicate.
         lead=1 for `run_scanned` stacks (a scan axis precedes the usual
         layout). Identity on the trivial topology."""
-        if self.mesh is None:
-            return x
-        from repro.launch import sharding as sh_rules
-        an = self.shard_spec.axis_name
-        out = dict(x)
-        main = {k: v for k, v in x.items()
-                if k not in ("tokens", "labels", "fresh")}
-        main = jax.device_put(main, sh_rules.fleet_shardings(
-            self.mesh, main, axis_pos=1 + lead, axis_name=an,
-            fleet_size=self.Vp))
-        out.update(main)
-        for k in ("tokens", "labels", "fresh"):
-            if k in x:
-                out[k] = jax.device_put(x[k], sh_rules.fleet_shardings(
-                    self.mesh, x[k], axis_pos=lead, axis_name=an,
-                    fleet_size=self.Vp))
-        return out
+        with self.telemetry.span("place", round=self._round_no):
+            if self.mesh is None:
+                return x
+            from repro.launch import sharding as sh_rules
+            an = self.shard_spec.axis_name
+            out = dict(x)
+            main = {k: v for k, v in x.items()
+                    if k not in ("tokens", "labels", "fresh")}
+            main = jax.device_put(main, sh_rules.fleet_shardings(
+                self.mesh, main, axis_pos=1 + lead, axis_name=an,
+                fleet_size=self.Vp))
+            out.update(main)
+            for k in ("tokens", "labels", "fresh"):
+                if k in x:
+                    out[k] = jax.device_put(x[k], sh_rules.fleet_shardings(
+                        self.mesh, x[k], axis_pos=lead, axis_name=an,
+                        fleet_size=self.Vp))
+            return out
 
     def _place_carry(self, carry: Dict[str, Any]) -> Dict[str, Any]:
         """Initial carry placement: per-vehicle UCB statistics shard over
@@ -539,8 +546,31 @@ class FusedRoundEngine:
     def _stage_round(self, allow_fresh: Sequence[bool]
                      ) -> Tuple[Dict[str, Any], List[Any]]:
         """Advance mobility one tick and stage every array the fused round
-        program needs. Returns (x, fresh_trees); fresh_trees[t] is a fleet-
-        stacked max_rank draw (zeros when not staged this round).
+        program needs (:meth:`_stage_arrays`), under the host span
+        ``repro.stage``. The span and the counters take the round's local
+        steps staged (``staged_steps``: Σ counts), the lane-steps the
+        megastep computes whatever the coverage (``lane_steps``: T · Vp ·
+        local_steps), the vehicles in coverage (``active``) and the fresh
+        adapter draws (``fresh``), all from the host arrays just staged."""
+        with self.telemetry.span("stage", round=self._round_no) as sp:
+            x, fresh = self._stage_arrays(allow_fresh)
+            active = x["active"].sum(axis=1)
+            n = {"staged_steps": int(x["counts"].sum()),
+                 "lane_steps": self.T * self.Vp * self.steps_full,
+                 "active": int(active.sum()),
+                 "fresh": int(sum(a for a, f in zip(active, allow_fresh)
+                                  if f))}
+            sp.set(**n)
+        tel = self.telemetry
+        tel.add("staged_steps", n["staged_steps"])
+        tel.add("lane_steps", n["lane_steps"])
+        tel.add("fresh_draws", n["fresh"])
+        return x, fresh
+
+    def _stage_arrays(self, allow_fresh: Sequence[bool]
+                      ) -> Tuple[Dict[str, Any], List[Any]]:
+        """The staging itself. Returns (x, fresh_trees); fresh_trees[t] is
+        a fleet-stacked max_rank draw (zeros when not staged this round).
 
         ``round_view``'s active mask is already presence-gated (dynamic
         fleets), so absent vehicles stage as inactive lanes: zero step
@@ -706,32 +736,64 @@ class FusedRoundEngine:
         return self._per_shard(accuracy, fleet_args=())(params, gad, batch)
 
     def _round_step(self, carry, x, data):
+        """The round program's Python body. It runs only while JAX traces
+        the program, so the ``retraces`` counter counts traces. Each task's
+        stages run under device scopes ``task<t>/round.<stage>``, and the
+        budget reallocation after the tasks under ``round.alloc``."""
+        n = self.telemetry.add("retraces")
+        with self.telemetry.span("retrace", retraces=n):
+            outs = []
+            for ti in range(self.T):
+                with telemetry.scope(f"task{ti}"):
+                    outs.append(self._task_step(ti, carry, x, data))
+            rec = {k: [o["rec"][k] for o in outs] for k in outs[0]["rec"]}
+            with telemetry.scope("round.alloc"):
+                consumed = jnp.stack(rec["energy"])
+                accs = jnp.stack(rec["accuracy"])
+                alloc = carry["alloc"]
+                if self.spec.energy_scheduler:
+                    alloc = energy_alloc.step_scan(alloc, self.cfg.energy,
+                                                   consumed, accs)
+                else:
+                    alloc = AllocState(budgets=alloc.budgets,
+                                       difficulty=alloc.difficulty,
+                                       round=alloc.round + 1)
+
+            out_carry = {"ucb": [o["ucb"] for o in outs],
+                         "merged": [o["merged"] for o in outs],
+                         "has_merged": jnp.stack([o["has_m"] for o in outs]),
+                         "alloc": alloc, "round": carry["round"] + 1}
+            if not self.tier_trivial:
+                out_carry["partials"] = [o["partials"] for o in outs]
+                out_carry["partial_w"] = jnp.stack([o["pw"] for o in outs])
+                out_carry["partial_age"] = jnp.stack([o["page"]
+                                                      for o in outs])
+            if not self.part_trivial:
+                for k in ("buf_delta", "buf_w", "buf_age", "buf_dest"):
+                    out_carry[k] = [o[k] for o in outs]
+            out_rec = {k: jnp.stack(v) for k, v in rec.items()}
+            out_rec["budgets"] = carry["alloc"].budgets
+            if self.check:
+                out_rec["check"] = {k: [o["check"][k] for o in outs]
+                                    for k in ("dist", "new", "ranks")}
+            return out_carry, out_rec
+
+    def _task_step(self, ti, carry, x, data) -> Dict[str, Any]:
+        """Task ``ti``'s part of the round program: its new carry entries
+        and its record, each stage (the numbered comments) under its device
+        scope."""
         cfg = self.cfg
         ucb_cfg = cfg.ucb
         mcfg = cfg.mobility
         params = data["params"]
         round_idx = carry["round"]
-        budgets = carry["alloc"].budgets
+        state = carry["ucb"][ti]
+        out: Dict[str, Any] = {}
 
-        new_ucb, new_merged = [], []
-        has_m_out = []
-        new_partials, new_pw, new_page = [], [], []
-        new_bdelta, new_bw, new_bage, new_bdest = [], [], [], []
-        rec: Dict[str, List[Any]] = {k: [] for k in (
-            "accuracy", "latency", "energy", "reward", "lambda", "mean_rank",
-            "active", "departing", "handoffs", "fallbacks", "comm_params",
-            "n_kept", "has_m")}
-        if not self.part_trivial:
-            for k in ("deferred", "released", "rel_weight"):
-                rec[k] = []
-        check: Dict[str, List[Any]] = {"dist": [], "new": [], "ranks": []}
-
-        for ti in range(self.T):
-            state = carry["ucb"][ti]
+        # 1. intra-task rank selection (Algorithm 2, vectorized)
+        with telemetry.scope("round.select"):
             act = x["active"][ti]
             dep = x["departing"][ti]
-
-            # 1. intra-task rank selection (Algorithm 2, vectorized)
             arms = ucb_dual.select_ranks(state, ucb_cfg, act)
             arm_c = jnp.clip(arms, 0, None)
             ranks = self.cand[arm_c]                       # (V,) int32
@@ -750,9 +812,10 @@ class FusedRoundEngine:
             else:
                 scale_arg = scale_v
 
-            # 2. adapter distribution: shared seeded SVD of the merged
-            #    delta, truncated per vehicle by rank mask — or the staged
-            #    fresh draws while no aggregate exists yet
+        # 2. adapter distribution: shared seeded SVD of the merged
+        #    delta, truncated per vehicle by rank mask — or the staged
+        #    fresh draws while no aggregate exists yet
+        with telemetry.scope("round.redistribute"):
             def dist_svd(m):
                 svd = agg.merged_svd(m, self.Rmax, seed=round_idx)
                 return agg.factors_for_ranks(svd, rmask, self.S0)
@@ -767,15 +830,18 @@ class FusedRoundEngine:
             # lane-parallel (identity on the trivial topology)
             dist = self._constrain(dist)
 
-            # 3. fleet megastep: local fine-tuning + held-out local eval
+        # 3. fleet megastep: local fine-tuning + held-out local eval
+        with telemetry.scope("round.train"):
             new_ads = self._constrain(self._per_shard(
                 self._train_fleet, fleet_args=(1, 2, 3, 4, 5))(
                 params, dist, scale_arg, x["tokens"][ti], x["labels"][ti],
                 x["counts"][ti]))
+        with telemetry.scope("round.local_eval"):
             local_acc = self._per_shard(self._eval_fleet, fleet_args=(1, 2))(
                 params, new_ads, scale_arg, self.local_eval[ti])
 
-            # 4. §III-C four-stage costs over the staged channel
+        # 4. §III-C four-stage costs over the staged channel
+        with telemetry.scope("round.costs"):
             costs = cm.vehicle_round_costs_vec(
                 freq=self.freq, comp_power=self.comp_power,
                 tx_power=self.dev_tx, flops_per_sample=self.flops_ps,
@@ -829,12 +895,14 @@ class FusedRoundEngine:
             n_active = jnp.sum(act)
             n_kept = jnp.sum(contribute)
 
-            # 6. rank-padded fleet aggregation (zero-weight lanes are
-            #    exact no-ops); empty rounds leave the merged delta alone.
-            #    Trivial tier: one global reduction, synced every round.
-            #    Non-trivial tier: segment-sum per-RSU partials, then a
-            #    staleness-weighted merge into the global adapter every
-            #    sync_period rounds — all inside this same jit program.
+        # 6. rank-padded fleet aggregation (zero-weight lanes are exact
+        #    no-ops); empty rounds leave the merged delta alone. Trivial
+        #    tier: one global reduction, synced every round. Non-trivial
+        #    tier: segment-sum per-RSU partials, then a staleness-weighted
+        #    merge into the global adapter every sync_period rounds — all
+        #    inside this same jit program.
+        rec: Dict[str, Any] = {}
+        with telemetry.scope("round.aggregate"):
             if not self.part_trivial:
                 # --- semi-sync participation: age → release → drop →
                 # admit, all dense masked lane algebra (host mirror:
@@ -943,9 +1011,9 @@ class FusedRoundEngine:
                     carry["merged"][ti]))
                 has_m = carry["has_merged"][ti] | do_merge
                 # a synced window resets: only new uploads count next time
-                new_partials.append(parts_out)
-                new_pw.append(jnp.where(is_sync, 0.0, pw))
-                new_page.append(jnp.where(is_sync, 0.0, page))
+                out["partials"] = parts_out
+                out["pw"] = jnp.where(is_sync, 0.0, pw)
+                out["page"] = jnp.where(is_sync, 0.0, page)
 
             if not self.part_trivial:
                 # buffer state out: deferred lanes admit this round's
@@ -966,15 +1034,16 @@ class FusedRoundEngine:
                 buf_dest_out = jnp.where(
                     defer, x["assoc"][ti],
                     jnp.where(keep_buf, bdest, -1)).astype(jnp.int32)
-                new_bdelta.append(self._constrain(buf_delta_out))
-                new_bw.append(self._constrain(buf_w_out))
-                new_bage.append(self._constrain(buf_age_out))
-                new_bdest.append(self._constrain(buf_dest_out))
-                rec["deferred"].append(jnp.sum(defer).astype(jnp.int32))
-                rec["released"].append(jnp.sum(release).astype(jnp.int32))
-                rec["rel_weight"].append(jnp.sum(relw).astype(jnp.float32))
+                out["buf_delta"] = self._constrain(buf_delta_out)
+                out["buf_w"] = self._constrain(buf_w_out)
+                out["buf_age"] = self._constrain(buf_age_out)
+                out["buf_dest"] = self._constrain(buf_dest_out)
+                rec["deferred"] = jnp.sum(defer).astype(jnp.int32)
+                rec["released"] = jnp.sum(release).astype(jnp.int32)
+                rec["rel_weight"] = jnp.sum(relw).astype(jnp.float32)
 
-            # 7. global eval on the task's held-out set
+        # 7. global eval on the task's held-out set
+        with telemetry.scope("round.global_eval"):
             def do_eval(m):
                 return self._global_eval(params, m, self.eval_batches[ti],
                                          round_idx)
@@ -988,10 +1057,11 @@ class FusedRoundEngine:
                                lambda m: jnp.zeros((), jnp.float32),
                                merged_out)
 
-            # 8. dual update with the task's current budget
+        # 8. dual update with the task's current budget
+        with telemetry.scope("round.dual"):
             state_new, info = ucb_dual.update(
                 state, ucb_cfg, arms, per_v_reward, per_v_energy,
-                budgets[ti].astype(jnp.float32))
+                carry["alloc"].budgets[ti].astype(jnp.float32))
             # per-vehicle bandit statistics stay fleet-sharded round over
             # round (their (Vp, K) leaves hit the fleet rule; the scalar
             # dual state is untouched)
@@ -1017,54 +1087,17 @@ class FusedRoundEngine:
             comm = jnp.sum(jnp.where(contribute, self.payload_arm_i[arm_c],
                                      0))
 
-            new_ucb.append(state_new)
-            new_merged.append(merged_out)
-            has_m_out.append(has_m)
-            rec["accuracy"].append(acc)
-            rec["latency"].append(lat)
-            rec["energy"].append(e_t)
-            rec["reward"].append(reward_t)
-            rec["lambda"].append(info["lambda"])
-            rec["mean_rank"].append(mean_rank)
-            rec["active"].append(n_active.astype(jnp.int32))
-            rec["departing"].append(jnp.sum(dep).astype(jnp.int32))
-            rec["handoffs"].append(jnp.sum(hoff).astype(jnp.int32))
-            rec["fallbacks"].append(fb)
-            rec["comm_params"].append(comm)
-            rec["n_kept"].append(n_kept.astype(jnp.int32))
-            rec["has_m"].append(has_m)
-            if self.check:
-                check["dist"].append(dist)
-                check["new"].append(new_ads)
-                check["ranks"].append(ranks)
-
-        consumed = jnp.stack(rec["energy"])
-        accs = jnp.stack(rec["accuracy"])
-        alloc = carry["alloc"]
-        if self.spec.energy_scheduler:
-            alloc = energy_alloc.step_scan(alloc, cfg.energy, consumed, accs)
-        else:
-            alloc = AllocState(budgets=alloc.budgets,
-                               difficulty=alloc.difficulty,
-                               round=alloc.round + 1)
-
-        out_carry = {"ucb": new_ucb, "merged": new_merged,
-                     "has_merged": jnp.stack(has_m_out),
-                     "alloc": alloc, "round": round_idx + 1}
-        if not self.tier_trivial:
-            out_carry["partials"] = new_partials
-            out_carry["partial_w"] = jnp.stack(new_pw)
-            out_carry["partial_age"] = jnp.stack(new_page)
-        if not self.part_trivial:
-            out_carry["buf_delta"] = new_bdelta
-            out_carry["buf_w"] = new_bw
-            out_carry["buf_age"] = new_bage
-            out_carry["buf_dest"] = new_bdest
-        out_rec = {k: jnp.stack(v) for k, v in rec.items()}
-        out_rec["budgets"] = budgets
+        out.update(ucb=state_new, merged=merged_out, has_m=has_m)
+        out["rec"] = dict(
+            rec, accuracy=acc, latency=lat, energy=e_t, reward=reward_t,
+            mean_rank=mean_rank, active=n_active.astype(jnp.int32),
+            departing=jnp.sum(dep).astype(jnp.int32),
+            handoffs=jnp.sum(hoff).astype(jnp.int32), fallbacks=fb,
+            comm_params=comm, n_kept=n_kept.astype(jnp.int32), has_m=has_m,
+            **{"lambda": info["lambda"]})
         if self.check:
-            out_rec["check"] = check
-        return out_carry, out_rec
+            out["check"] = {"dist": dist, "new": new_ads, "ranks": ranks}
+        return out
 
     # ------------------------------------------------------------------
     # Public API
@@ -1074,18 +1107,26 @@ class FusedRoundEngine:
         Host work is staging (mobility tick, channel draws, data batches)
         and the small record fetch — no per-group dispatch, no thread pool,
         no recompilation under churn."""
-        if self._carry is None:
-            self._init_carry()
-        x, fresh = self._stage_round(
-            [not hm for hm in self._has_merged_host])
-        x = self._place_x(x)
-        data = {"params": self._params, "fresh": fresh}
-        self._carry, rec = self._jit_round(self._carry, x, data)
-        if self.check:
-            self._run_check(x, rec.pop("check"))
-        host = jax.device_get({k: v for k, v in rec.items() if k != "check"})
-        out = self._record(host)
-        self._sync_sim()
+        tel = self.telemetry
+        rnd = self._round_no = len(self.sim.history)
+        with tel.step("round", rnd):
+            if self._carry is None:
+                self._init_carry()
+            x, fresh = self._stage_round(
+                [not hm for hm in self._has_merged_host])
+            x = self._place_x(x)
+            data = {"params": self._params, "fresh": fresh}
+            # the call site, not the method: a caller may replace
+            # _jit_round (the benchmark's recorder does)
+            with tel.span("dispatch", round=rnd):
+                self._carry, rec = self._jit_round(self._carry, x, data)
+            if self.check:
+                self._run_check(x, rec.pop("check"))
+            with tel.span("wait", round=rnd):
+                host = jax.device_get({k: v for k, v in rec.items()
+                                       if k != "check"})
+            out = self._record(host)
+            self._sync_sim()
         return out
 
     def run_scanned(self, rounds: int) -> List[Dict[str, Any]]:
@@ -1111,6 +1152,8 @@ class FusedRoundEngine:
                              " use run()/run_round(), not run_scanned()")
         if self._carry is None:
             self._init_carry()
+        tel = self.telemetry
+        base = len(self.sim.history)
         xs_list: List[Dict[str, Any]] = []
         fresh_list: List[List[Any]] = []
         # trivial tier only: ONE staged draw per task (its first covered
@@ -1130,7 +1173,11 @@ class FusedRoundEngine:
                 # model yet; post-sync the program ignores them
                 allow = [not self._has_merged_host[t]
                          for t in range(self.T)]
-            x, fresh = self._stage_round(allow)
+            # each round's step span covers its staging; the device runs
+            # every round in the one call under repro.dispatch below
+            self._round_no = base + r
+            with tel.step("round", base + r):
+                x, fresh = self._stage_round(allow)
             fresh_list.append(fresh)
             if self.tier_trivial:
                 for t in range(self.T):
@@ -1170,6 +1217,7 @@ class FusedRoundEngine:
                 lambda *leaves: jnp.stack(leaves),
                 *[fresh_list[r][t] for r in range(rounds)])
                 for t in range(self.T) if staged_fresh[t]]
+        self._round_no = base
         xs = self._place_x(xs, lead=1)
         if self.tier_trivial:
             data = {"params": self._params, "fresh": fresh_const,
@@ -1178,10 +1226,13 @@ class FusedRoundEngine:
             # the hierarchy body reads only params — fresh rides in xs
             data = {"params": self._params}
         fn = self._scan_fn(rounds, staged_fresh)
-        self._carry, recs = fn(self._carry, xs, data)
-        host = jax.device_get(recs)
+        with tel.span("dispatch", round=base, rounds=rounds):
+            self._carry, recs = fn(self._carry, xs, data)
+        with tel.span("wait", round=base, rounds=rounds):
+            host = jax.device_get(recs)
         outs = []
         for r in range(rounds):
+            self._round_no = base + r
             outs.append(self._record(jax.tree_util.tree_map(
                 lambda a: a[r], host)))
         self._sync_sim()
@@ -1227,88 +1278,91 @@ class FusedRoundEngine:
     # ------------------------------------------------------------------
     def _record(self, h: Dict[str, Any]) -> Dict[str, Any]:
         """Shape one round's device outputs into the serial history schema."""
-        sim = self.sim
-        tasks = []
-        for ti in range(self.T):
-            tasks.append({
-                "task": sim.tasks[ti].name,
-                "accuracy": float(h["accuracy"][ti]),
-                "latency": float(h["latency"][ti]),
-                "energy": float(h["energy"][ti]),
-                "reward": float(h["reward"][ti]),
-                "lambda": float(h["lambda"][ti]),
-                "mean_rank": float(h["mean_rank"][ti]),
-                "active": int(h["active"][ti]),
-                "departing": int(h["departing"][ti]),
-                "handoffs": int(h["handoffs"][ti]),
-                "fallbacks": {i: int(h["fallbacks"][ti][i])
-                              for i in range(3)},
-                "comm_params": int(h["comm_params"][ti]),
-                "budget": float(h["budgets"][ti]),
-            })
-            if "deferred" in h:
-                # buffer dynamics, mirroring the serial _finish_task record
-                tasks[-1]["deferred"] = int(h["deferred"][ti])
-                tasks[-1]["released"] = int(h["released"][ti])
-                tasks[-1]["rel_weight"] = float(h["rel_weight"][ti])
-            # non-trivial tiers only gain a global model at a sync round,
-            # so mirror the program's has_merged flag (for the trivial
-            # tier it is equivalent to n_kept > 0)
-            if bool(h["has_m"][ti]):
-                self._has_merged_host[ti] = True
-        rec = {
-            "round": len(sim.history),
-            "tasks": tasks,
-            "budgets": [float(b) for b in h["budgets"]],
-            "reward": float(sum(t["reward"] for t in tasks)),
-            "energy": float(sum(t["energy"] for t in tasks)),
-            "latency": float(max((t["latency"] for t in tasks),
-                                 default=0.0)),
-            "accuracy": float(np.mean([t["accuracy"] for t in tasks])),
-        }
-        sim.history.append(rec)
-        return rec
+        with self.telemetry.span("record", round=self._round_no):
+            sim = self.sim
+            tasks = []
+            for ti in range(self.T):
+                tasks.append({
+                    "task": sim.tasks[ti].name,
+                    "accuracy": float(h["accuracy"][ti]),
+                    "latency": float(h["latency"][ti]),
+                    "energy": float(h["energy"][ti]),
+                    "reward": float(h["reward"][ti]),
+                    "lambda": float(h["lambda"][ti]),
+                    "mean_rank": float(h["mean_rank"][ti]),
+                    "active": int(h["active"][ti]),
+                    "departing": int(h["departing"][ti]),
+                    "handoffs": int(h["handoffs"][ti]),
+                    "fallbacks": {i: int(h["fallbacks"][ti][i])
+                                  for i in range(3)},
+                    "comm_params": int(h["comm_params"][ti]),
+                    "budget": float(h["budgets"][ti]),
+                })
+                if "deferred" in h:
+                    # buffer dynamics, mirroring the serial _finish_task record
+                    tasks[-1]["deferred"] = int(h["deferred"][ti])
+                    tasks[-1]["released"] = int(h["released"][ti])
+                    tasks[-1]["rel_weight"] = float(h["rel_weight"][ti])
+                # non-trivial tiers only gain a global model at a sync round,
+                # so mirror the program's has_merged flag (for the trivial
+                # tier it is equivalent to n_kept > 0)
+                if bool(h["has_m"][ti]):
+                    self._has_merged_host[ti] = True
+            rec = {
+                "round": len(sim.history),
+                "tasks": tasks,
+                "budgets": [float(b) for b in h["budgets"]],
+                "reward": float(sum(t["reward"] for t in tasks)),
+                "energy": float(sum(t["energy"] for t in tasks)),
+                "latency": float(max((t["latency"] for t in tasks),
+                                     default=0.0)),
+                "accuracy": float(np.mean([t["accuracy"] for t in tasks])),
+            }
+            sim.history.append(rec)
+            self.telemetry.add("rounds")
+            return rec
 
     def _sync_sim(self) -> None:
         """Mirror the device carry back onto the simulator so host-side
         consumers (checkpointing, eval_adapters, summary) stay coherent."""
-        sim = self.sim
-        c = self._carry
-        if self.n_shards == 1:
-            sim.ucb_states = list(c["ucb"])
-        else:
-            # un-permute the (Vp, K) slot layout back to original lanes so
-            # host consumers (checkpointing, engine switches) see the same
-            # per-vehicle state an unsharded engine would hand them
-            idx = jnp.asarray(self.slot, jnp.int32)
-            sim.ucb_states = [ucb_dual.UCBDualState(
-                counts=s.counts[idx], reward_sum=s.reward_sum[idx],
-                energy_sum=s.energy_sum[idx], lam=s.lam, round=s.round)
-                for s in c["ucb"]]
-        sim.alloc = AllocState(budgets=c["alloc"].budgets,
-                               difficulty=c["alloc"].difficulty,
-                               round=int(c["alloc"].round))
-        r = int(c["round"])
-        for t in range(self.T):
-            if self._has_merged_host[t]:
-                sim.servers[t].load_merged(c["merged"][t], r)
+        with self.telemetry.span("sync", round=self._round_no):
+            sim = self.sim
+            c = self._carry
+            if self.n_shards == 1:
+                sim.ucb_states = list(c["ucb"])
             else:
-                sim.servers[t].round = r
-            if not self.tier_trivial:
-                sim.servers[t].load_partials(
-                    agg.unstack_partials(c["partials"][t], self.K),
-                    np.asarray(c["partial_w"][t]),
-                    np.asarray(c["partial_age"][t]))
-            if not self.part_trivial:
-                # un-permute slot → vehicle order (lane_array[slot[v]] is
-                # vehicle v's lane; trivial topology: identity)
-                sl = self.slot
-                sim.servers[t].load_buffer(
-                    jax.tree_util.tree_map(
-                        lambda a: np.asarray(a)[sl], c["buf_delta"][t]),
-                    np.asarray(c["buf_w"][t])[sl],
-                    np.asarray(c["buf_age"][t])[sl],
-                    np.asarray(c["buf_dest"][t])[sl])
+                # un-permute the (Vp, K) slot layout back to original lanes so
+                # host consumers (checkpointing, engine switches) see the same
+                # per-vehicle state an unsharded engine would hand them
+                idx = jnp.asarray(self.slot, jnp.int32)
+                sim.ucb_states = [ucb_dual.UCBDualState(
+                    counts=s.counts[idx], reward_sum=s.reward_sum[idx],
+                    energy_sum=s.energy_sum[idx], lam=s.lam, round=s.round)
+                    for s in c["ucb"]]
+            sim.alloc = AllocState(budgets=c["alloc"].budgets,
+                                   difficulty=c["alloc"].difficulty,
+                                   round=int(c["alloc"].round))
+            r = int(c["round"])
+            for t in range(self.T):
+                if self._has_merged_host[t]:
+                    sim.servers[t].load_merged(c["merged"][t], r)
+                else:
+                    sim.servers[t].round = r
+                if not self.tier_trivial:
+                    sim.servers[t].load_partials(
+                        agg.unstack_partials(c["partials"][t], self.K),
+                        np.asarray(c["partial_w"][t]),
+                        np.asarray(c["partial_age"][t]))
+                if not self.part_trivial:
+                    # un-permute slot → vehicle order (lane_array[slot[v]] is
+                    # vehicle v's lane; trivial topology: identity)
+                    sl = self.slot
+                    sim.servers[t].load_buffer(
+                        jax.tree_util.tree_map(
+                            lambda a: np.asarray(a)[sl], c["buf_delta"][t]),
+                        np.asarray(c["buf_w"][t])[sl],
+                        np.asarray(c["buf_age"][t])[sl],
+                        np.asarray(c["buf_dest"][t])[sl])
 
     # ------------------------------------------------------------------
     def _run_check(self, x, check) -> None:

@@ -48,6 +48,7 @@ from repro.core import lora as lora_lib
 from repro.launch import sharding as sh
 from repro.launch.adapter_cache import PagedAdapter
 from repro.models import transformer as T
+from repro.telemetry import Telemetry
 
 
 def make_prefill_step(cfg: ModelConfig, lora: LoRAConfig, mesh, *,
@@ -199,7 +200,8 @@ BlockAllocator`, lanes grow block-by-block as their streams lengthen, and
         self._admit_order: list = []     # lanes, oldest admission first
 
         window = self.spec.sliding_window
-        self._traces = 0
+        # the decode program's traces, as the counter "traces"
+        self.telemetry = Telemetry()
         one_dev = jax.sharding.SingleDeviceSharding(jax.devices()[0])
         # Pin explicit input shardings: the jit cache key must not depend
         # on whether an argument is committed (host-side lane surgery —
@@ -222,8 +224,8 @@ BlockAllocator`, lanes grow block-by-block as their streams lengthen, and
             def serve_decode_paged(params, adapters, scales, tokens,
                                    states, pools, tables, positions):
                 # host-side body: runs ONLY when jax (re)traces the
-                # program, so _traces counts compiled decode variants
-                self._traces += 1
+                # program, so "traces" counts compiled decode variants
+                self.telemetry.add("traces")
                 logits, new_states, written = vlane(
                     params, pools, adapters, scales, tokens, states,
                     tables, positions)
@@ -255,7 +257,7 @@ BlockAllocator`, lanes grow block-by-block as their streams lengthen, and
                 # host-side body: runs ONLY when jax (re)traces the
                 # program, so this counter is the number of compiled
                 # decode variants
-                self._traces += 1
+                self.telemetry.add("traces")
                 return vlane(params, adapters, scales, tokens, caches,
                              positions)
 
@@ -422,7 +424,7 @@ BlockAllocator`, lanes grow block-by-block as their streams lengthen, and
         fastpath may key extra cache entries on input provenance
         (committed/fresh) that all share ONE lowering, so the private
         ``_cache_size`` would overcount."""
-        return self._traces
+        return self.telemetry.counters.get("traces", 0)
 
 
 # ---------------------------------------------------------------------------

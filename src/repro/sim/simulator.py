@@ -47,6 +47,7 @@ from repro.federated.server import RSUServer
 from repro.models import transformer as T
 from repro.sim.channel import ChannelConfig, ChannelModel
 from repro.sim.mobility_model import MobilityModel, MobilitySimConfig
+from repro.telemetry import Telemetry
 
 
 @dataclass
@@ -224,6 +225,9 @@ class IoVSimulator:
             cfg.lora.candidate_ranks,
             np.array([p.freq for p in self.dev_profiles]))
 
+        # host spans and counters (repro.telemetry): the fused engine
+        # shares them, so sim.fused.telemetry is this object
+        self.telemetry = Telemetry()
         # --- fused engine (one jit program per round; see fused_engine) ---
         self.fused = None
         if self.engine in ("fused", "fused_check", "fused_sharded"):
@@ -767,7 +771,10 @@ class IoVSimulator:
             chunk = min(ck.interval, n - done)
             out.extend(self.fused.run_scanned(chunk))
             done += chunk
-            save_checkpoint(self)
+            # tagged with the last round it holds
+            with self.telemetry.span("checkpoint",
+                                     round=len(self.history) - 1):
+                save_checkpoint(self)
         return out
 
     # ------------------------------------------------------------------
@@ -783,7 +790,9 @@ class IoVSimulator:
                       f"E={rec['energy']:.0f}J lat={rec['latency']:.1f}s")
             if ck.enabled and len(self.history) % ck.interval == 0:
                 from repro.checkpoint.carry import save_checkpoint
-                save_checkpoint(self)
+                with self.telemetry.span("checkpoint",
+                                         round=len(self.history) - 1):
+                    save_checkpoint(self)
         return self.history
 
     # ------------------------------------------------------------------
