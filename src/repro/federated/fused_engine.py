@@ -6,7 +6,7 @@ thread pool, with host round-trips between UCB-DUAL selection, training,
 §III-C accounting and aggregation. This module compiles the ENTIRE round —
 
     ucb_dual.select_ranks  →  SVD redistribution at per-vehicle ranks
-    →  vmap×scan local fine-tuning of the whole fleet
+    →  vmap×scan local fine-tuning of the vehicles in coverage
     →  §III-C cost accounting + §IV-E fallback decisions
     →  rank-padded merged-delta aggregation  →  global eval
     →  ucb_dual.update + Algorithm-1 budget reallocation
@@ -56,6 +56,16 @@ reduction — so churning fleets (rush-hour arrivals, staged departures,
 RSU outages) reuse the exact-no-op padding invariants unchanged: no shape
 in the program depends on who is present, and serial/fused parity holds in
 churning-fleet regimes (tests/test_scenarios.py).
+
+Active-lane compaction: the local training and the local eval compute
+only the lanes in coverage. A stable permutation puts them first and a
+loop runs over ``ceil(n_active / C)`` chunks of ``C`` lanes
+(:func:`over_active`, ``C`` from :func:`chunk_width`), gathering each
+chunk, vmapping the per-lane step over it and scattering the result back.
+The trip count is traced, so the program still compiles once; a lane
+outside coverage keeps its distributed adapter bit for bit and reads a
+local accuracy of 0, which nothing uses (its reward, energy and
+aggregation weight are masked, and ``ucb_dual.update`` skips arm −1).
 
 Device-sharded fleets (ISSUE 5): with ``engine="fused_sharded"`` (or a
 non-trivial ``SimConfig.shard``) the SAME round program runs with its
@@ -129,6 +139,51 @@ def fleet_slots(num_vehicles: int, num_shards: int,
         raise ValueError(f"unknown placement {placement!r}")
     per_shard = vp // num_shards
     return (v % num_shards) * per_shard + v // num_shards, vp
+
+
+def chunk_width(lanes: int) -> int:
+    """Lanes per chunk of the compacted fleet megastep over ``lanes``
+    lanes (the whole fleet, or one shard's slice of it): the smallest
+    divisor of ``lanes`` that is at least 4, or ``lanes`` where it has
+    none. A divisor, so the chunks partition the lanes and no lane is
+    written twice. On a TPU v5e at ViT-Base width a lane's local training
+    and eval cost less the narrower the chunk, from 24 lanes down to 4,
+    the narrowest measured (PERF.md), and a narrow chunk computes fewer
+    lanes outside coverage."""
+    return next(d for d in range(min(4, lanes), lanes + 1)
+                if lanes % d == 0)
+
+
+def computed_lanes(active: np.ndarray, n_shards: int = 1) -> int:
+    """Lanes the compacted megastep computes for a staged ``(T, Vp)``
+    active mask whose lane axis splits into ``n_shards`` contiguous
+    shards: each shard's lanes in coverage, rounded up to whole chunks."""
+    per = np.asarray(active).reshape(active.shape[0], n_shards, -1)
+    c = chunk_width(per.shape[-1])
+    return int((c * -(-per.sum(axis=-1) // c)).sum())
+
+
+def over_active(fn, active, lanes, init):
+    """``fn`` run only on the lanes where ``active`` is set, ``C`` =
+    :func:`chunk_width` lanes at a time. ``lanes`` is a tuple of trees
+    whose leaves lead with the lane axis; ``fn`` maps the same tuple cut
+    to ``C`` lanes to a tree of ``init``'s structure cut to ``C`` lanes.
+    A stable permutation puts the active lanes first, in lane order; the
+    loop gathers chunk i of it, applies ``fn`` and scatters the result
+    into ``init``, for ``ceil(n_active / C)`` trips (zero when no lane is
+    active). Lanes never gathered keep their ``init`` entries; inactive
+    lanes that fill out the last chunk go through ``fn`` like the rest."""
+    c = chunk_width(active.shape[0])
+    order = jnp.argsort(jnp.logical_not(active), stable=True)
+    trips = (jnp.sum(active, dtype=jnp.int32) + c - 1) // c
+
+    def body(i, out):
+        idx = jax.lax.dynamic_slice_in_dim(order, i * c, c)
+        got = fn(*jax.tree_util.tree_map(lambda a: a[idx], lanes))
+        return jax.tree_util.tree_map(
+            lambda o, g: o.at[idx].set(g, unique_indices=True), out, got)
+
+    return jax.lax.fori_loop(0, trips, body, init)
 
 
 def adapter_deviation(got, ref, start) -> Tuple[float, float]:
@@ -548,8 +603,10 @@ class FusedRoundEngine:
         """Advance mobility one tick and stage every array the fused round
         program needs (:meth:`_stage_arrays`), under the host span
         ``repro.stage``. The span and the counters take the round's local
-        steps staged (``staged_steps``: Σ counts), the lane-steps the
-        megastep computes whatever the coverage (``lane_steps``: T · Vp ·
+        steps staged (``staged_steps``: Σ counts), the lane-steps of the
+        full fleet width (``lane_steps``: T · Vp · local_steps), those the
+        compacted megastep computes (``computed_steps``: the lanes in
+        coverage rounded up to whole chunks, :func:`computed_lanes`, times
         local_steps), the vehicles in coverage (``active``) and the fresh
         adapter draws (``fresh``), all from the host arrays just staged."""
         with self.telemetry.span("stage", round=self._round_no) as sp:
@@ -557,6 +614,8 @@ class FusedRoundEngine:
             active = x["active"].sum(axis=1)
             n = {"staged_steps": int(x["counts"].sum()),
                  "lane_steps": self.T * self.Vp * self.steps_full,
+                 "computed_steps": self.steps_full * computed_lanes(
+                     x["active"], self.n_shards),
                  "active": int(active.sum()),
                  "fresh": int(sum(a for a, f in zip(active, allow_fresh)
                                   if f))}
@@ -564,6 +623,7 @@ class FusedRoundEngine:
         tel = self.telemetry
         tel.add("staged_steps", n["staged_steps"])
         tel.add("lane_steps", n["lane_steps"])
+        tel.add("computed_steps", n["computed_steps"])
         tel.add("fresh_draws", n["fresh"])
         return x, fresh
 
@@ -644,12 +704,17 @@ class FusedRoundEngine:
     # ------------------------------------------------------------------
     # The fused round program (traced once; one XLA cache entry)
     # ------------------------------------------------------------------
-    def _train_fleet(self, params, adapters, scales, tokens, labels, counts):
-        """Whole-fleet local fine-tuning: vmap over vehicles, scan over
-        local steps, Adam on the rank-padded adapter tree (frozen base).
-        Per-vehicle step budgets freeze updates past each budget (§IV-E),
-        reproducing the serial dynamics; the rank-padded tail stays
-        identically zero (see core.lora rank-padding invariant)."""
+    def _train_fleet(self, params, adapters, scales, tokens, labels, counts,
+                     active):
+        """Whole-fleet local fine-tuning of the lanes in coverage
+        (``active``), compacted by :func:`over_active`: vmap over a chunk's
+        vehicles, scan over local steps, Adam on the rank-padded adapter
+        tree (frozen base). Per-vehicle step budgets freeze updates past
+        each budget (§IV-E), reproducing the serial dynamics; a lane with
+        budget 0 (every inactive lane) returns its adapter bit for bit,
+        whether it fills out a chunk or is never gathered. The rank-padded
+        tail stays identically zero (see core.lora rank-padding
+        invariant)."""
         cfg, lora_max, opt = self.model_cfg, self.lora_max, self.opt
         n_steps = self.steps_full
 
@@ -681,7 +746,9 @@ class FusedRoundEngine:
                  jnp.arange(n_steps, dtype=jnp.int32)))
             return ad
 
-        return jax.vmap(one)(adapters, scales, tokens, labels, counts)
+        return over_active(jax.vmap(one), active,
+                           (adapters, scales, tokens, labels, counts),
+                           adapters)
 
     def _per_shard(self, fn, fleet_args: Sequence[int]):
         """``fn`` (a whole-fleet function) run on each device's own lanes.
@@ -689,9 +756,9 @@ class FusedRoundEngine:
         construction, so they run under ``shard_map``: arguments at
         ``fleet_args`` split along their leading (Vp,) axis, the rest
         (frozen params, the shared eval batch) replicate, and each device
-        traces the vmap over its Vp / n_shards lanes. With no fleet
-        argument (the global eval) every device computes the same
-        replicated result. The Pallas kernels inside then compile per
+        compacts its own Vp / n_shards lanes, with its own trip count and
+        no collective in the loop. With no fleet argument (the global
+        eval) every device computes the same replicated result. The Pallas kernels inside then compile per
         device; XLA cannot partition a Mosaic call on its own. Identity on
         the trivial topology."""
         if self.mesh is None:
@@ -706,12 +773,20 @@ class FusedRoundEngine:
                                  out_specs=out, check_vma=False)(*args)
         return per_shard
 
-    def _eval_fleet(self, params, adapters, scales, batch):
+    def _eval_fleet(self, params, adapters, scales, batch, active):
+        """Accuracy of each lane in coverage on the task's held-out local
+        batch, compacted like :meth:`_train_fleet`; 0 on every other lane
+        (nothing reads it there)."""
         def ev(ad, scale):
             _, m = T.loss_fn(params, ad, self.model_cfg, self.lora_max,
                              batch, scale=scale)
             return m["accuracy"]
-        return jax.vmap(ev)(adapters, scales)
+
+        def chunk(ad, scale, act):
+            return jnp.where(act, jax.vmap(ev)(ad, scale), 0.0)
+
+        return over_active(chunk, active, (adapters, scales, active),
+                           jnp.zeros(active.shape, jnp.float32))
 
     def _global_eval(self, params, merged, batch, round_idx):
         """Accuracy of a task's merged adapter on its held-out set, through
@@ -830,15 +905,17 @@ class FusedRoundEngine:
             # lane-parallel (identity on the trivial topology)
             dist = self._constrain(dist)
 
-        # 3. fleet megastep: local fine-tuning + held-out local eval
+        # 3. fleet megastep: local fine-tuning + held-out local eval of
+        #    the lanes in coverage, compacted (each shard its own lanes)
         with telemetry.scope("round.train"):
             new_ads = self._constrain(self._per_shard(
-                self._train_fleet, fleet_args=(1, 2, 3, 4, 5))(
+                self._train_fleet, fleet_args=(1, 2, 3, 4, 5, 6))(
                 params, dist, scale_arg, x["tokens"][ti], x["labels"][ti],
-                x["counts"][ti]))
+                x["counts"][ti], act))
         with telemetry.scope("round.local_eval"):
-            local_acc = self._per_shard(self._eval_fleet, fleet_args=(1, 2))(
-                params, new_ads, scale_arg, self.local_eval[ti])
+            local_acc = self._per_shard(self._eval_fleet,
+                                        fleet_args=(1, 2, 4))(
+                params, new_ads, scale_arg, self.local_eval[ti], act)
 
         # 4. §III-C four-stage costs over the staged channel
         with telemetry.scope("round.costs"):

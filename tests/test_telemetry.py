@@ -22,7 +22,7 @@ ROUNDS = 5
 
 def _cfg(**kw):
     from repro.sim.simulator import SimConfig
-    base = dict(method="ours", rounds=ROUNDS, num_vehicles=6, num_tasks=2,
+    base = dict(method="ours", rounds=ROUNDS, num_vehicles=8, num_tasks=2,
                 seed=3, local_steps=2, engine="fused")
     base.update(kw)
     return SimConfig(**base)
@@ -112,6 +112,15 @@ def traced(tmp_path_factory):
     return sim, staged, path
 
 
+def _computed_steps(f, history):
+    """Σ over rounds and tasks of C · ceil(n_t / C) · local_steps, from
+    each task's recorded count of vehicles in coverage."""
+    from repro.federated.fused_engine import chunk_width
+    c = chunk_width(f.Vp)
+    return sum(c * -(-t["active"] // c) * f.steps_full
+               for r in history for t in r["tasks"])
+
+
 def test_counters_after_five_rounds(traced):
     sim, staged, _ = traced
     f = sim.fused
@@ -124,6 +133,10 @@ def test_counters_after_five_rounds(traced):
     assert c["staged_steps"] == sum(staged)
     assert c["lane_steps"] == ROUNDS * f.T * f.Vp * f.steps_full
     assert 0 < c["staged_steps"] <= c["lane_steps"]
+    # the compacted megastep computes each task's lanes in coverage
+    # rounded up to whole chunks of C lanes
+    assert c["computed_steps"] == _computed_steps(f, sim.history)
+    assert c["staged_steps"] <= c["computed_steps"] < c["lane_steps"]
     assert c["fresh_draws"] > 0
     assert f.telemetry is sim.telemetry
 
@@ -144,6 +157,10 @@ def test_trace_holds_every_span_with_its_args(traced):
     assert stage["round"] == 0
     assert stage["staged_steps"] == staged[0]
     assert stage["lane_steps"] == sim.fused.T * sim.fused.Vp * 2
+    assert stage["computed_steps"] == _computed_steps(sim.fused,
+                                                      sim.history[:1])
+    assert (stage["staged_steps"] <= stage["computed_steps"]
+            <= stage["lane_steps"])
     assert stage["active"] == sum(t["active"] for t in sim.history[0]["tasks"])
     assert stage["fresh"] == stage["active"]     # first round: all fresh
     assert found["round"][0]["step_num"] == 0

@@ -153,8 +153,9 @@ def test_serve_decode_compiles(one_chip, tpu_kernels, block_size):
 
 
 def test_fleet_local_train_compiles_and_fits(one_chip, tpu_kernels):
-    """The fused engine's whole-fleet local-training megastep (vmap over
-    24 vehicles × scan over 3 Adam steps) at full width fits one chip."""
+    """The fused engine's whole-fleet local-training megastep (a loop
+    over chunks of the 24 vehicles in coverage, vmap over a chunk × scan
+    over 3 Adam steps) at full width fits one chip."""
     from repro.federated.fused_engine import FusedRoundEngine
     from repro.optim import adam
     cfg = vit_base_paper()
@@ -174,10 +175,56 @@ def test_fleet_local_train_compiles_and_fits(one_chip, tpu_kernels):
             (jnp.zeros((V,), jnp.float32), jnp.zeros((V, R), jnp.float32)),
             jnp.zeros((V, steps, B, S), jnp.int32),
             jnp.zeros((V, steps, B), jnp.int32),
-            jnp.zeros((V,), jnp.int32))
+            jnp.zeros((V,), jnp.int32), jnp.zeros((V,), bool))
     c = _compile(lambda *a: FusedRoundEngine._train_fleet(engine, *a),
                  *_spec(one_chip, args))
     assert "tpu_custom_call" in c.as_text()
+    mem = c.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert total < HBM_BYTES, mem
+
+
+def test_compacted_megastep_compiles_and_fits(one_chip, tpu_kernels):
+    """The round's two compacted stages as the round program runs them,
+    at the cell's shapes: local training of the lanes in coverage, then
+    their eval on the task's 32 held-out sequences. Each is one loop
+    whose traced trip count follows the active mask, so the program has
+    one body per stage and fits one chip."""
+    from repro.federated.fused_engine import FusedRoundEngine, chunk_width
+    from repro.optim import adam
+    cfg = vit_base_paper()
+    lora = LoRAConfig(rank=R, max_rank=R, candidate_ranks=(2, 4, 8, 16, 32))
+    key = jax.random.PRNGKey(0)
+    params = jax.eval_shape(lambda: T.init_params(key, cfg,
+                                                  dtype=jnp.float32))
+    fleet = jax.eval_shape(lambda: jax.vmap(
+        lambda k: T.init_adapters(k, cfg, lora, rank=R))(
+            jax.random.split(key, V)))
+    steps = 3
+    engine = types.SimpleNamespace(model_cfg=cfg, lora_max=lora,
+                                   opt=adam(5e-3), steps_full=steps,
+                                   remat=True)
+
+    def megastep(params, ads, scales, tok, lab, counts, active, batch):
+        new = FusedRoundEngine._train_fleet(engine, params, ads, scales,
+                                            tok, lab, counts, active)
+        acc = FusedRoundEngine._eval_fleet(engine, params, new, scales,
+                                           batch, active)
+        return new, acc
+
+    args = (params, fleet,
+            (jnp.zeros((V,), jnp.float32), jnp.zeros((V, R), jnp.float32)),
+            jnp.zeros((V, steps, B, S), jnp.int32),
+            jnp.zeros((V, steps, B), jnp.int32),
+            jnp.zeros((V,), jnp.int32), jnp.zeros((V,), bool),
+            {"tokens": jnp.zeros((32, S), jnp.int32),
+             "labels": jnp.zeros((32,), jnp.int32)})
+    assert chunk_width(V) == 4
+    c = _compile(megastep, *_spec(one_chip, args))
+    text = c.as_text()
+    assert "tpu_custom_call" in text
+    assert text.count("while(") >= 2
     mem = c.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
@@ -215,13 +262,13 @@ def test_sharded_round_pieces_compile(fleet_mesh, tpu_kernels):
             jax.random.split(key, V)))
     train = engine._per_shard(
         functools.partial(FusedRoundEngine._train_fleet, engine),
-        fleet_args=(1, 2, 3, 4, 5))
+        fleet_args=(1, 2, 3, 4, 5, 6))
     c = _compile(train, _spec(repl, params), *_spec(lanes, (
         fleet,
         (jnp.zeros((V,), jnp.float32), jnp.zeros((V, R), jnp.float32)),
         jnp.zeros((V, steps, B, S), jnp.int32),
         jnp.zeros((V, steps, B), jnp.int32),
-        jnp.zeros((V,), jnp.int32))))
+        jnp.zeros((V,), jnp.int32), jnp.zeros((V,), bool))))
     assert "tpu_custom_call" in c.as_text()
 
     merged = jax.eval_shape(lambda: FusedRoundEngine._merged_zeros_like(
