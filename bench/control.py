@@ -6,17 +6,12 @@ a cell's own size.
     python3 bench/control.py --workload <name> --seeds 1,2,3 [--seconds 10]
 
 For each seed it drives one run of the cell (set-up, a short window at
-the cell's load, the reference) and prints one JSON line with:
-
-- ``program``: the numbers the run compares (what sound runs read);
-- ``control``: the same numbers with the plain reference put in the
-  program's place, computed in bfloat16;
-- ``default``: the same with the reference at the default matrix-product
-  precision (one bfloat16 pass on the TPU, as the program's float32
-  products take): how far rounding alone moves each number;
-- ``faults``: the numbers with a fault planted in the reference put in the
-  program's place: half of every batch left out (the loss the mean over
-  the rest), and rounds that hand back the state they started from.
+the cell's load, the reference) and prints one JSON line with the readings
+that the cell's driver gives (its ``readings``): the numbers the run
+compares (``program``: what sound runs read), the same numbers with the
+plain reference put in the program's place computed in bfloat16 (the
+control) and at the default matrix-product precision (how far rounding
+alone moves each number), and with faults planted in that reference.
 
 The benchmark's own runs do not run this.
 """
@@ -38,23 +33,6 @@ from bench import harness  # noqa: E402
 from bench.harness import Manifest  # noqa: E402
 
 
-def fleet_readings(ref, seed, model, lora, env, recorded):
-    """Program, control, rounding and fault readings of one fleet run."""
-    import jax.numpy as jnp
-
-    def replay(**kw):
-        return ref.replay(seed, model, lora, env, recorded, **kw)
-
-    honest = replay()
-    against = lambda view: ref.compare(view, honest, recorded)
-    return {"program": against(ref.program_view(recorded)),
-            "control": against(replay(dt=jnp.bfloat16)),
-            "default": against(replay(precision="default")),
-            "faults": {"half_batch": against(replay(half_batch=True)),
-                       "state_unchanged": against(
-                           ref.unchanged(honest, recorded))}}
-
-
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
@@ -63,15 +41,6 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     manifest = Manifest.load()
     from bench.run import prepare
-    from bench.reference import fleet as ref
-    inner = ref.check_rounds
-    got = {}
-
-    def check_rounds(seed_, model, lora, env, recorded):
-        got.update(fleet_readings(ref, seed_, model, lora, env, recorded))
-        return got["program"]
-
-    ref.check_rounds = check_rounds
     for seed in [int(s) for s in args.seeds.split(",")]:
         ns = argparse.Namespace(workload=args.workload, seed=seed,
                                 seconds=args.seconds, trace=0)
@@ -79,13 +48,20 @@ def main(argv=None) -> int:
         run.t_start = time.perf_counter()
         driver = harness.load_module(
             harness.BENCH / "drivers" / f"{run.traffic['driver']}.py")
-        got.clear()
+        got = {}
+
+        def check(run_, *recorded):
+            got.update(driver.readings(run_, *recorded))
+            return got["program"]
+
         with harness.CompileLog() as log:
             run.compile_log = log
-            driver.run(run)
-        print(json.dumps({"workload": args.workload, "seed": seed, **got}),
-              flush=True)
-    ref.check_rounds = inner
+            out = driver.run(run, check=check)
+        print(json.dumps({"workload": args.workload, "seed": seed,
+                          "metrics": out.metrics,
+                          "attempted": out.attempted, "failed": out.failed,
+                          "memory_peak_bytes": run.memory_peak_bytes,
+                          **got}), flush=True)
     return 0
 
 

@@ -1,11 +1,12 @@
 """Driver ``fleet_rounds``: communication rounds of the fused fleet engine.
 
 Set-up builds one ``IoVSimulator`` from the traffic file and the seed,
-hands it base weights drawn by the harness (``bench/weights.py``), and
-drives it through its first ``checked_rounds`` rounds with the window's
-own call, ``sim.run(1)``. The first of these compiles the round program.
-What each of those rounds was fed (the staged batches, masks and channel
-rates), the bandit statistics it started from and returned, and its
+hands it base weights drawn by the harness (``bench/weights.py``) and the
+RSUs and vehicles moving as the traffic file's ``mobility_seed`` draws
+them, and drives it through its first ``checked_rounds`` rounds with the
+window's own call, ``sim.run(1)``. The first of these compiles the round
+program. What each of those rounds was fed (the staged batches, masks and
+channel rates), the bandit statistics it started from and returned, and its
 energy are copied to the host; for the first ``trained_rounds`` also the
 adapter draws and the merged updates. The checked rounds reach past
 exploration: from the sixth on, a vehicle that has been in coverage every
@@ -14,7 +15,10 @@ round has tried every arm and exploits.
 The window calls ``sim.run(1)`` until ``--seconds`` have passed and ends
 when the round running at that moment has finished, so ``round_s`` is the
 window over whole rounds; each round ends when its record reaches the
-host.
+host. A round's work follows the vehicles in coverage, so every seed
+gets the same motion: the seed draws the weights, the data, the devices
+and the channels, and the window's rounds compute the same lanes whatever
+it is.
 
 After the window the device memory peak is read, the simulator is freed,
 and the plain reference (``bench/reference/fleet.py``) checks each
@@ -23,10 +27,11 @@ the recorded feed.
 """
 from __future__ import annotations
 
+import dataclasses
 import gc
 import sys
 import time
-from typing import Any, Dict, List
+from typing import Any, Callable, Dict, List, Optional
 
 import numpy as np
 
@@ -48,6 +53,22 @@ def sim_config(run: Run, mcfg):
                          for k, v in tr["lora"].items()})
     return SimConfig(train_arch=mcfg, seed=run.seed, lora=lora,
                      engine=tr["engine"], **tr["sim"])
+
+
+def fixed_mobility(sim, seed: int) -> None:
+    """Put in the simulator's place RSUs and vehicles that move as the
+    simulator would draw them from ``seed``, before its first round."""
+    from repro.sim.mobility_model import MobilityModel
+    cfg = sim.cfg
+    ms = dataclasses.replace(cfg.mobility_sim, num_vehicles=cfg.num_vehicles,
+                             seed=seed)
+    rsus = MobilityModel.place_rsus(
+        cfg.num_tasks, ms.area, ms.coverage_radius, seed=seed,
+        layout=ms.rsu_layout, num_per_task=cfg.rsu_tier.num_rsus_per_task)
+    sim.rsu_groups = [[r for r in rsus if r.task_id == t]
+                      for t in range(cfg.num_tasks)]
+    sim.rsus = [g[0] for g in sim.rsu_groups]
+    sim.mobility = MobilityModel(ms, rsus)
 
 
 def _host(tree):
@@ -170,7 +191,40 @@ def environment(sim) -> Dict[str, Any]:
     }
 
 
-def run(run: Run) -> Outcome:
+def check_recorded(run: Run, env, recorded) -> Dict[str, float]:
+    """The compared numbers of the recorded rounds."""
+    from bench.reference import fleet as ref
+    return ref.check_rounds(run.seed, run.config["model"],
+                            run.traffic["lora"], env, recorded)
+
+
+def readings(run: Run, env, recorded) -> Dict[str, Any]:
+    """Program, control, rounding and fault readings of one run: the
+    compared numbers of the program; of the plain reference in its place
+    computed in bfloat16 (the control) and at the default matrix-product
+    precision (one bfloat16 pass on the TPU, as the program's float32
+    products take: how far rounding alone moves each number); and of that
+    reference with a fault planted: half of every batch left out (the loss
+    the mean over the rest), and rounds that hand back the state they
+    started from."""
+    import jax.numpy as jnp
+    from bench.reference import fleet as ref
+    seed, model, lora = run.seed, run.config["model"], run.traffic["lora"]
+
+    def replay(**kw):
+        return ref.replay(seed, model, lora, env, recorded, **kw)
+
+    honest = replay()
+    against = lambda view: ref.compare(view, honest, recorded)
+    return {"program": against(ref.program_view(recorded)),
+            "control": against(replay(dt=jnp.bfloat16)),
+            "default": against(replay(precision="default")),
+            "faults": {"half_batch": against(replay(half_batch=True)),
+                       "state_unchanged": against(
+                           ref.unchanged(honest, recorded))}}
+
+
+def run(run: Run, check: Optional[Callable] = None) -> Outcome:
     import jax
     import jax.numpy as jnp
     from repro.models import transformer as T
@@ -185,6 +239,7 @@ def run(run: Run) -> Outcome:
 
     with run.spans.span("build"):
         sim = IoVSimulator(scfg)
+        fixed_mobility(sim, int(tr["mobility_seed"]))
         shapes = jax.eval_shape(lambda: T.init_params(
             jax.random.PRNGKey(0), mcfg, dtype=jnp.float32))
         base = weights.draw_tree(shapes, run.seed, stream=0)
@@ -240,10 +295,10 @@ def run(run: Run) -> Outcome:
     gc.collect()
 
     from bench.reference import fleet as ref
+    ref.use(run.config["reference"])
     exploits = ref.exploit_decisions(recorded)
     t_ref = time.perf_counter()
-    readings = ref.check_rounds(run.seed, run.config["model"],
-                                run.traffic["lora"], env, recorded)
+    numbers = (check or check_recorded)(run, env, recorded)
     rounds = len(work)
     print(f"fleet: {rounds} rounds in {t1 - t0:.3f} s; compiles in the "
           f"window {len(in_window)}; checked rounds {len(recorded)} "
@@ -252,7 +307,7 @@ def run(run: Run) -> Outcome:
     return Outcome(
         metrics={"setup_s": setup_s, "round_s": (t1 - t0) / rounds},
         attempted=rounds, failed=0 if finite else rounds,
-        checks=[run.check(k, v) for k, v in readings.items()],
+        checks=[run.check(k, v) for k, v in numbers.items()],
         counters={"window_rounds": work,
                   "compiles_in_window": len(in_window),
                   "exploit_decisions": exploits})

@@ -29,7 +29,9 @@ directions into every entry of the trained adapters. So a redistributed
 round is compared by the size of the change it made to each merged update,
 not entry by entry (see PERF.md).
 
-It imports nothing of the program and takes none of its weights.
+It imports nothing of the program and takes none of its weights. The
+model's own forward and loss come from the reference file that the
+configuration names (:func:`use`).
 """
 from __future__ import annotations
 
@@ -41,7 +43,19 @@ import jax.numpy as jnp
 import numpy as np
 
 from bench import weights as wgen
-from bench.reference import transformer as ref
+
+# the model reference: the file that the configuration's ``reference``
+# names, bound by :func:`use` before a check
+ref = None
+
+
+def use(path: str):
+    """Bind the model reference that a configuration's ``reference``
+    names (a path from the checkout's root)."""
+    global ref
+    from bench import harness
+    ref = harness.load_module(harness.ROOT / path)
+    return ref
 
 LR_B1, LR_B2, ADAM_EPS = 0.9, 0.999, 1e-8
 

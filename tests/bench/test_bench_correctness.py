@@ -26,7 +26,7 @@ def harness():
 
 
 def _run(harness, cell, config_file, traffic_file, model, traffic_edit,
-         seconds, seed):
+         seconds, seed, check=None):
     import jax
     cfg = harness.load_json(ROOT / "bench" / "configs" / config_file)
     cfg["model"].update(model)
@@ -42,7 +42,7 @@ def _run(harness, cell, config_file, traffic_file, model, traffic_edit,
             seed=seed, seconds=seconds, trace=False,
             devices=jax.devices(), compile_log=log,
             t_start=time.perf_counter())
-        out = driver.run(run)
+        out = driver.run(run, check=check)
     return out
 
 
@@ -54,9 +54,9 @@ def _small_fleet(tr):
     tr["sim"].update(num_vehicles=6, num_tasks=2)
 
 
-def run_fleet(harness, seed=2 ** 31 + 101):
+def run_fleet(harness, seed=2 ** 31 + 101, check=None):
     return _run(harness, FLEET_CELL, "vit-base-paper.json",
-                "fleet-paper.json", TINY_VIT, _small_fleet, 1.0, seed)
+                "fleet-paper.json", TINY_VIT, _small_fleet, 1.0, seed, check)
 
 
 def ok(outcome):
@@ -100,23 +100,71 @@ def test_fleet_half_batch_left_out_fails(harness, monkeypatch):
     assert {c.name: c.ok for c in out.checks}["merged_rel"] is False
 
 
-def test_fleet_bfloat16_control_fails(harness, monkeypatch):
-    from bench import control
-    from bench.reference import fleet as ref
-    got = {}
-    inner = ref.check_rounds
+def _fleet_driver(harness):
+    return harness.load_module(ROOT / "bench" / "drivers" /
+                               "fleet_rounds.py")
 
-    def check_rounds(seed, model, lora, env, recorded):
-        got.update(control.fleet_readings(ref, seed, model, lora, env,
-                                          recorded))
-        return inner(seed, model, lora, env, recorded)
-    monkeypatch.setattr(ref, "check_rounds", check_rounds)
-    run_fleet(harness)
+
+def test_fleet_bfloat16_control_fails(harness):
+    driver = _fleet_driver(harness)
+    got = {}
+
+    def check(run, env, recorded):
+        got.update(driver.readings(run, env, recorded))
+        return got["program"]
+    run_fleet(harness, check=check)
     limits = harness.load_json(ROOT / "bench" / "limits" /
                                f"{FLEET_CELL}.json")
     assert any(got["control"][k] > limits[k] for k in limits)
     for fault in got["faults"].values():
         assert any(fault[k] > limits[k] for k in limits)
+
+
+# -- the fleet's motion ------------------------------------------------------
+
+@pytest.mark.parametrize("seed", [0, 39, 2 ** 31 + 101])
+def test_fixed_mobility_draws_as_the_simulator(harness, seed):
+    """Drawn from the simulator's own seed, the motion that the driver
+    puts in its place is the simulator's own: the same RSUs and the same
+    vehicles in coverage, tick after tick."""
+    from repro.config import get_arch
+    from repro.sim.simulator import IoVSimulator, SimConfig
+    mcfg = get_arch("vit-base-paper").with_overrides(**TINY_VIT)
+    sim = IoVSimulator(SimConfig(seed=seed, num_vehicles=6, num_tasks=2,
+                                 train_arch=mcfg, engine="fused"))
+    own, own_groups = sim.mobility, sim.rsu_groups
+    _fleet_driver(harness).fixed_mobility(sim, seed)
+    assert sim.mobility is not own
+    assert ([[r.xy for r in g] for g in sim.rsu_groups]
+            == [[r.xy for r in g] for g in own_groups])
+    for _ in range(8):
+        own.step()
+        sim.mobility.step()
+        np.testing.assert_array_equal(sim.mobility.pos, own.pos)
+        for g, og in zip(sim.rsu_groups, own_groups):
+            np.testing.assert_array_equal(
+                sim.mobility.round_view_group(g)["active"],
+                own.round_view_group(og)["active"])
+
+
+def test_fleet_motion_is_the_same_for_every_seed(harness):
+    """Two seeds feed the checked rounds the same vehicles in coverage and
+    different data."""
+    driver = _fleet_driver(harness)
+    fed = []
+
+    def check(run, env, recorded):
+        fed.append([r["x"] for r in recorded])
+        return driver.check_recorded(run, env, recorded)
+    for seed in (2 ** 31 + 101, 7):
+        assert ok(run_fleet(harness, seed=seed, check=check))
+    a, b = fed
+    assert len(a) == len(b) > 0
+    for ra, rb in zip(a, b):
+        np.testing.assert_array_equal(ra["active"], rb["active"])
+        np.testing.assert_array_equal(ra["counts"], rb["counts"])
+    assert any(not np.array_equal(ra["tokens"], rb["tokens"])
+               for ra, rb in zip(a, b))
 
 
 # -- the reference's pieces on hand-made rounds -----------------------------

@@ -232,11 +232,12 @@ def test_recorded_trace_reduces(bench):
 # -- per-layer readout ------------------------------------------------------
 
 def _readout(bench, monkeypatch, silent):
-    """The cell's per-layer line from stub readers that each read 1.0,
-    except ``silent``, which reads nothing."""
+    """The per-layer line of a cell that reports ``silent``, from stub
+    readers that each read 1.0, except ``silent``, which reads nothing."""
     import types
     man = bench.Manifest(json.loads((ROOT / "BENCHMARK.json").read_text()))
-    cell = man.raw["workloads"][0]
+    cell = next(w for w in man.raw["workloads"]
+                if any(m["name"] == silent for m in man.per_layer(w["name"])))
 
     def load_module(path, name=None):
         stem = Path(path).stem
@@ -265,7 +266,9 @@ def test_a_metric_that_reads_nothing(bench, monkeypatch, silent):
 
 # -- refusal without a TPU --------------------------------------------------
 
-@pytest.mark.parametrize("cell", ["fleet.vit-base.paper"])
+@pytest.mark.parametrize("cell", [
+    w["name"] for w in json.loads((ROOT / "BENCHMARK.json").read_text())[
+        "workloads"]])
 def test_run_refuses_without_a_tpu(cell):
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     p = subprocess.run(
